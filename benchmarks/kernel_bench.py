@@ -17,7 +17,7 @@ import numpy as np
 from repro.core import FPTree, GFPStats, ItemOrder, TISTree, gfp_growth, mine_frequent
 from repro.data import bernoulli_db
 from repro.kernels.itemset_count import itemset_counts, itemset_counts_ref
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS
+from repro.roofline.peaks import PEAKS
 
 from .common import Row, timeit
 
@@ -44,16 +44,17 @@ def _kernel_rows() -> List[Row]:
         us_k = timeit(lambda: itemset_counts(tx, tgt, wts).block_until_ready())
         assert (np.asarray(out_ref) == np.asarray(out_k)).all()
 
-        # TPU-target estimate: the kernel streams N*W words once per K-tile
-        # and does N*K*W uint32 ops + N*K*C MACs (VPU).
+        # v5e estimate: the kernel streams N*W words once per K-tile and
+        # does N*K*W uint32 ops + N*K*C MACs (VPU).
+        v5e = PEAKS["TPU v5 lite"]
         bytes_hbm = n * w * 4 * max(1, k // 256) + k * w * 4 + n * c * 4
         ops = n * k * (w + c)
-        t_mem = bytes_hbm / HBM_BW
-        t_cmp = ops / (PEAK_FLOPS / 2)  # VPU int ops, not MXU — conservative /2
+        t_mem = bytes_hbm / v5e.hbm_bytes_per_s
+        t_cmp = ops / (v5e.bf16_flops / 2)  # VPU int ops, not MXU — /2
         tag = f"kernel[N={n},K={k},W={w}]"
         rows.append((f"{tag}/jnp_oracle", us_ref, f"containments={n * k}"))
         rows.append((f"{tag}/pallas_interpret", us_k,
-                     f"tpu_roofline_est_us={max(t_mem, t_cmp) * 1e6:.1f}"))
+                     f"v5e_roofline_est_us={max(t_mem, t_cmp) * 1e6:.1f}"))
     return rows
 
 

@@ -27,6 +27,9 @@ def main() -> None:
                              "obs"])
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from .common import emit
 
     suites = {}

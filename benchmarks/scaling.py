@@ -1,6 +1,7 @@
 """Distributed-engine strong scaling: the same counting workload on host
-meshes of 1..8 CPU devices (subprocess — this process keeps 1 device).
-Derived column: speedup vs 1 device and exactness check."""
+meshes of 1..8 CPU devices.  The subprocess is pinned to the CPU — it times
+forced host devices, never a chip, which this process may hold.  Derived
+column: speedup vs 1 device and exactness check."""
 from __future__ import annotations
 
 import json
@@ -54,6 +55,7 @@ def run() -> List[Row]:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"      # forced host devices, not the chip
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
@@ -62,6 +64,6 @@ def run() -> List[Row]:
     base = data["1"]
     rows: List[Row] = []
     for d, us in data.items():
-        rows.append((f"scaling[devices={d}]", us,
+        rows.append((f"scaling[cpu_host_devices={d}]", us,
                      f"speedup_vs_1dev={base / us:.2f}x"))
     return rows

@@ -2,7 +2,8 @@
 
 Two measurements, written together to ``BENCH_shard.json``:
 
-* **throughput** (subprocess, 8 forced host devices): the same micro-batched
+* **throughput** (subprocess pinned to the CPU, 8 forced host devices — never
+  a chip, which the parent process may hold): the same micro-batched
   query workload served by the synchronous single-device ``CountServer``
   (the PR-2/PR-3 path) and by sharded stores at 1/2/4/8 shards laid over a
   host mesh (one ``resident_distributed_counts`` psum launch per flush),
@@ -33,6 +34,7 @@ BATCHES = [16, 64]
 SHARDS = [1, 2, 4, 8]
 MAX_DELAY_MS = 50.0
 JITTER_MARGIN_MS = 25.0
+HOST_DEVICES = "cpu: 8 forced host devices"
 
 _SUBPROC = r"""
 import json, time
@@ -117,13 +119,15 @@ def _throughput_records() -> List[dict]:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"      # forced host devices, not the chip
     script = _SUBPROC % {"rows": ROWS, "items": ITEMS, "pool": POOL,
                          "batches": BATCHES, "shards": SHARDS}
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
         raise RuntimeError(proc.stderr[-2000:])
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return [dict(rec, devices=HOST_DEVICES)
+            for rec in json.loads(proc.stdout.strip().splitlines()[-1])]
 
 
 def _latency_record() -> dict:
@@ -166,6 +170,7 @@ def run(record: List[dict] | None = None) -> List[Row]:
                 + f"/batch={rec['batch']}")
         derived = (f"speedup_vs_single={rec['speedup_vs_single']:.2f}x"
                    if "speedup_vs_single" in rec else "baseline")
+        derived += f";devices={HOST_DEVICES}"
         rows.append((name, rec["us_per_query"], derived))
     lat = _latency_record()
     if record is not None:

@@ -12,6 +12,8 @@ exercises the same workload pattern.
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -26,13 +28,43 @@ CENSUS_COLUMNS: Tuple[Tuple[str, int], ...] = (
 assert sum(k for _, k in CENSUS_COLUMNS) == 115
 
 
+# rows drawn per chunk: bounds the float64 draw buffer at 64 MiB for 1,024
+# items, whatever the transaction count
+_BERNOULLI_CHUNK_DRAWS = 1 << 23
+
+
 def bernoulli_db(n_transactions: int, n_items: int, p_x: float, p_y: float,
                  seed: int = 0) -> Tuple[List[List[int]], np.ndarray]:
-    """Paper §4.3 simulation: returns (transactions, classes)."""
-    rng = np.random.default_rng(seed)
-    mat = rng.random((n_transactions, n_items)) < p_x
-    y = (rng.random(n_transactions) < p_y).astype(np.int32)
-    tx = [np.flatnonzero(row).tolist() for row in mat]
+    """Paper §4.3 simulation: returns (transactions, classes).
+
+    Item draws are row-major from ``default_rng(seed)``, then one class draw
+    per row.  Row chunks are drawn in parallel, each from the seed's stream
+    advanced to the chunk's first draw, so the output for a seed does not
+    depend on the chunking."""
+    seq = np.random.SeedSequence(seed)
+
+    def stream(draws_before: int) -> np.random.Generator:
+        bits = np.random.PCG64(seq)
+        bits.advance(draws_before)    # one 64-bit draw per float64
+        return np.random.Generator(bits)
+
+    def chunk(start: int) -> Tuple[np.ndarray, np.ndarray]:
+        rows = min(step, n_transactions - start)
+        hit = stream(start * n_items).random((rows, n_items)) < p_x
+        return (np.flatnonzero(hit) % max(n_items, 1),
+                np.count_nonzero(hit, axis=1))
+
+    step = max(1, _BERNOULLI_CHUNK_DRAWS // max(n_items, 1))
+    tx: List[List[int]] = []
+    with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as ex:
+        for items, per_row in ex.map(chunk, range(0, n_transactions, step)):
+            flat = items.tolist()
+            ends = np.cumsum(per_row)
+            tx.extend(map(flat.__getitem__,
+                          map(slice, (ends - per_row).tolist(),
+                              ends.tolist())))
+    y = (stream(n_transactions * n_items).random(n_transactions)
+         < p_y).astype(np.int32)
     return tx, y
 
 

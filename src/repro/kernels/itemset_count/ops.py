@@ -2,7 +2,7 @@
 
 Handles padding, layout transposition, backend selection (interpret mode on
 CPU — the kernel body executes in Python for correctness validation; compiled
-Mosaic on TPU), and a pure-jnp fallback for degenerate shapes.
+Mosaic on every accelerator), and a pure-jnp fallback for degenerate shapes.
 """
 from __future__ import annotations
 
@@ -21,15 +21,70 @@ from .kernel import itemset_counts_pallas
 from .ref import itemset_counts_ref, itemset_counts_ref_blocked
 
 __all__ = ["itemset_counts", "itemset_counts_into", "itemset_counts_ref",
-           "itemset_counts_ref_blocked"]
+           "itemset_counts_ref_blocked", "mxu_f32_exact", "checked_accum",
+           "weight_sum_bound"]
 
 # Unrolling the word loop beyond this is counter-productive; fall back to the
 # blocked jnp reference (still jit-compiled) for enormous item universes.
 MAX_KERNEL_WORDS = 64
 
+# mxu_f32 accumulates each launch's per-class weight sums in f32, which holds
+# integers exactly only below 2^24.
+MXU_F32_MAX_WEIGHT_SUM = 1 << 24
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Pallas interpret mode on the CPU backend only: an accelerator always
+    runs the compiled kernel."""
+    on_cpu = jax.default_backend() == "cpu"
+    if interpret is None:
+        return on_cpu
+    if interpret and not on_cpu:
+        raise ValueError(f"interpret=True on the {jax.default_backend()} "
+                         "backend: the kernel runs compiled there")
+    return interpret
+
+
+def weight_sum_bound(weights) -> int:
+    """The largest per-class |weight| sum of ``weights`` (N, C), read on the
+    host: the bound a caller that owns the weights computes once and passes
+    to every launch as ``weight_bound``."""
+    w = np.asarray(weights)
+    if w.size == 0:
+        return 0
+    if w.ndim == 1:
+        w = w[:, None]
+    return int(np.abs(w.astype(np.int64)).sum(axis=0).max())
+
+
+def mxu_f32_exact(weights, weight_bound: Optional[int] = None) -> bool:
+    """True when an ``accum='mxu_f32'`` launch over ``weights`` (N, C) is
+    exact: every class's |weight| sum stays below 2^24, which bounds every
+    f32 partial of the launch.  ``weight_bound`` is the caller's known upper
+    bound on those sums (a store's class totals); without it the weights
+    are read on the host."""
+    if weight_bound is None:
+        weight_bound = weight_sum_bound(weights)
+    return int(weight_bound) < MXU_F32_MAX_WEIGHT_SUM
+
+
+def checked_accum(requested: Optional[str], resolved: str, weights,
+                  weight_bound: Optional[int] = None) -> str:
+    """The accumulator a launch may use.  ``mxu_f32`` needs the weight-sum
+    bound: a tuned (resolved) pick that breaks it falls back to the exact
+    VPU path, an explicit request raises.  Under a jit trace the weights
+    are abstract — the callers that trace (``itemset_counts_into``, the
+    mesh launch) check the bound eagerly before tracing."""
+    if resolved != "mxu_f32" or (weight_bound is None and isinstance(
+            weights, jax.core.Tracer)) or mxu_f32_exact(weights, weight_bound):
+        return resolved
+    if requested == "mxu_f32":
+        raise ValueError(
+            "mxu_f32 accumulation is exact only while each class's weight "
+            f"sum per launch is < 2^24; weights of shape {tuple(weights.shape)} "
+            "exceed it — chunk the sweep (mining/stream.py) or use "
+            "accum='vpu_int32'")
+    return autotune.DEFAULT_ACCUM
 
 
 def itemset_counts(
@@ -42,6 +97,7 @@ def itemset_counts(
     interpret: Optional[bool] = None,
     use_kernel: bool = True,
     accum: Optional[str] = None,
+    weight_bound: Optional[int] = None,
 ) -> jnp.ndarray:             # (K, C) int32
     """Exact counts of every target itemset, per weight column (class).
 
@@ -50,13 +106,18 @@ def itemset_counts(
     the compiled-in defaults — callers pin explicit values to bypass it.
 
     ``accum='mxu_f32'`` routes the weighted reduction through the MXU in f32
-    (exact while each count < 2^24; enforced below) — the counting-kernel
-    §Perf variant."""
+    (exact while each class's weight sum per launch is < 2^24; enforced
+    below) — the counting-kernel §Perf variant.  A caller that knows an
+    upper bound on every class's weight sum passes it as ``weight_bound``,
+    so the check never copies device-resident weights to the host."""
     if weights.ndim == 1:
         weights = weights[:, None]
     n, w = tx_bits.shape
     k = tgt_bits.shape[0]
     c = weights.shape[1]
+    # checked before any route: interpret=True never passes on a chip, not
+    # even where the jnp reference below answers instead of the kernel
+    interpret = _interpret(interpret)
     if k == 0:
         return jnp.zeros((0, c), jnp.int32)
     if n == 0:
@@ -64,6 +125,7 @@ def itemset_counts(
     if not use_kernel or w > MAX_KERNEL_WORDS:
         return itemset_counts_ref_blocked(tx_bits, tgt_bits, weights)
 
+    requested = accum
     if block_k is None or block_n is None or accum is None:
         # Eager host-side resolution (n/k/w/c are concrete Python ints even
         # under a jit trace) so any jit cache downstream keys on the CONCRETE
@@ -72,17 +134,7 @@ def itemset_counts(
         block_k = cfg.block_k if block_k is None else block_k
         block_n = cfg.block_n if block_n is None else block_n
         accum = cfg.accum if accum is None else accum
-
-    if interpret is None:
-        interpret = _on_cpu()
-    if accum == "mxu_f32" and n >= (1 << 24):
-        # exactness bound: every partial sum is <= sum(|weights|) per column,
-        # and f32 holds integers exactly only below 2^24.  A real error, not
-        # an assert — `python -O` must not silently admit inexact counts.
-        raise ValueError(
-            "mxu_f32 accumulation is exact only for N < 2^24 rows per "
-            f"launch; got geometry (N={n}, K={k}, W={w}, C={c}) — chunk "
-            "the sweep (mining/stream.py) or use accum='vpu_int32'")
+    accum = checked_accum(requested, accum, weights, weight_bound)
 
     # Shrink blocks for small problems, keeping TPU-friendly minima.
     block_n = min(block_n, _round_up(n, 128))
@@ -107,19 +159,19 @@ def itemset_counts(
             if eager else obs.tracing.NOOP_SPAN)
     with span:
         t0 = time.perf_counter() if timed else 0.0
-        out_t = itemset_counts_pallas(
+        out = itemset_counts_pallas(
             tx_p.T, tgt_p, wt_p.T.astype(jnp.int32),
             block_k=block_k, block_n=block_n, interpret=interpret,
             accum=accum,
-        )                                                 # (C, K_pad)
+        )                                                 # (K_pad, C)
         if timed:
             # blocking gives a TRUE wall time; free on CPU (callers
             # materialize the counts immediately) but serializes a pipelined
             # TPU launch stream — obs.configure(kernel_timing=False) when
             # overlap matters
-            out_t.block_until_ready()
+            out.block_until_ready()
             record_launch(n, k, w, c, time.perf_counter() - t0)
-    return out_t.T[:k, :]
+    return out[:k, :]
 
 
 def _round_up(x: int, m: int) -> int:
@@ -131,8 +183,8 @@ def _round_up(x: int, m: int) -> int:
 # the small (K, C) count block device-resident and adds one chunk's counts per
 # call; donating the accumulator lets the compiler update it in place, so a
 # sweep allocates O(chunk) device memory regardless of total N.  Note the
-# mxu_f32 exactness bound (N < 2^24) then applies PER CHUNK — chunking makes
-# the MXU variant exact for unbounded N.
+# mxu_f32 exactness bound (weight sum < 2^24) then applies PER CHUNK —
+# chunking makes the MXU variant exact for unbounded N.
 # ---------------------------------------------------------------------------
 
 def _counts_into(acc, tx_bits, tgt_bits, weights, *, block_k, block_n,
@@ -165,9 +217,12 @@ def itemset_counts_into(
 ) -> jnp.ndarray:                 # (K, C) int32 = acc + chunk counts
     """``acc + itemset_counts(chunk)`` fused in one jit; acc stays on device.
 
-    Launch config resolves EAGERLY here (not inside the trace): the jit
-    cache is keyed on the static block/accum values, so a table swap between
-    calls must surface as different statics, not a stale cached trace."""
+    Launch config and interpret mode resolve EAGERLY here (not inside the
+    trace): the jit cache is keyed on the static block/accum values, so a
+    table swap between calls must surface as different statics, not a
+    stale cached trace — and the mxu_f32 weight bound can only be checked
+    on concrete weights."""
+    requested = accum
     if block_k is None or block_n is None or accum is None:
         wts = weights if weights.ndim == 2 else weights[:, None]
         cfg = autotune.resolve_launch_config(
@@ -176,6 +231,8 @@ def itemset_counts_into(
         block_k = cfg.block_k if block_k is None else block_k
         block_n = cfg.block_n if block_n is None else block_n
         accum = cfg.accum if accum is None else accum
+    accum = checked_accum(requested, accum, weights)
+    interpret = _interpret(interpret)
     donate = jax.default_backend() != "cpu"  # CPU donation warns, no-op
     return _counts_into_jit(donate)(
         acc, tx_bits, tgt_bits, weights, block_k=block_k, block_n=block_n,

@@ -13,6 +13,15 @@ from typing import Optional
 import numpy as np
 
 
+def _auto_axes(n: int):
+    """Auto axis types: the model code places activations with
+    ``with_sharding_constraint``, which ``jax.make_mesh``'s default
+    Explicit axes refuse."""
+    import jax
+
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     import jax
 
@@ -25,7 +34,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — the "
             "dry-run entrypoint must set XLA_FLAGS="
             "--xla_force_host_platform_device_count=512 before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=_auto_axes(len(shape)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -34,7 +44,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
 
     n = data * model
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:n])
+                         devices=jax.devices()[:n], axis_types=_auto_axes(2))
 
 
 def dp_size(mesh) -> int:

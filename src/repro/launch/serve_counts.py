@@ -92,6 +92,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import numpy as np
 
     from .. import obs

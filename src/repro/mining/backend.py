@@ -167,7 +167,8 @@ class DenseBackend(CountBackend):
         return self._single_chunk(
             lambda m: itemset_counts(self.db.bits, jnp.asarray(m),
                                      self.db.weights,
-                                     use_kernel=self.use_kernel),
+                                     use_kernel=self.use_kernel,
+                                     weight_bound=self.db.weight_bound),
             masks, start_chunk, init, on_chunk)
 
 

@@ -47,7 +47,7 @@ from typing import Optional
 import numpy as np
 
 from ..obs import REGISTRY
-from .stream import DEFAULT_STREAM_THRESHOLD_BYTES
+from .stream import device_stream_threshold_bytes
 
 # Decision thresholds (first-match order documented above).  These are the
 # hand-tuned FALLBACKS: thresholds passed as None resolve through the active
@@ -69,8 +69,9 @@ def _resolved_thresholds(stream_threshold_bytes, tiny_rows, min_depth):
         from ..roofline import autotune
         derived = autotune.derived_chooser_thresholds()
     if stream_threshold_bytes is None:
-        stream_threshold_bytes = derived.get("stream_threshold_bytes",
-                                             DEFAULT_STREAM_THRESHOLD_BYTES)
+        stream_threshold_bytes = derived.get("stream_threshold_bytes")
+    if stream_threshold_bytes is None:
+        stream_threshold_bytes = device_stream_threshold_bytes()
     if tiny_rows is None:
         tiny_rows = derived.get("tiny_rows", DEFAULT_TINY_ROWS)
     if min_depth is None:

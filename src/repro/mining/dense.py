@@ -27,10 +27,11 @@ import numpy as np
 from ..core.mra import Rule
 from ..core.tis import TISTree
 from ..kernels.itemset_count import itemset_counts
+from ..kernels.itemset_count.ops import weight_sum_bound
 from .encode import (ItemVocab, class_weights, dedup_rows, encode_bitmap,
                      encode_targets, project_columns)
 from .plan import TISSchedule, build_schedule, live_items
-from .stream import (DEFAULT_STREAM_THRESHOLD_BYTES, StreamingDB,
+from .stream import (StreamingDB, device_stream_threshold_bytes,
                      streaming_counts, streaming_mine_frequent)
 
 Item = Hashable
@@ -52,7 +53,7 @@ def _resolve_streaming(db, streaming: Optional[bool],
         return True
     if isinstance(db.bits, np.ndarray):
         return (db.bits.size + db.weights.size) * 4 > \
-            DEFAULT_STREAM_THRESHOLD_BYTES
+            device_stream_threshold_bytes()
     return False
 
 
@@ -73,7 +74,8 @@ def _count_block(db, masks: np.ndarray, *, use_kernel: bool, streaming: bool,
             np.asarray(db.bits), masks, np.asarray(db.weights),
             chunk_rows=chunk_rows, use_kernel=use_kernel))
     return np.asarray(itemset_counts(
-        db.bits, jnp.asarray(masks), db.weights, use_kernel=use_kernel))
+        db.bits, jnp.asarray(masks), db.weights, use_kernel=use_kernel,
+        weight_bound=db.weight_bound))
 
 
 @dataclass
@@ -84,6 +86,10 @@ class DenseDB:
     weights: jnp.ndarray   # (U, C) int32 per-class multiplicities
     n_rows: int            # original N (sum of weights)
     n_classes: int
+    # largest per-class weight sum, taken on the host when the base is
+    # built: every launch checks the mxu_f32 bound against it instead of
+    # reading the device weights back (None: read them per launch)
+    weight_bound: Optional[int] = None
 
     @staticmethod
     def encode(
@@ -104,7 +110,8 @@ class DenseDB:
             w = class_weights(classes, n_classes)
         ub, uw = dedup_rows(bits, w)
         return DenseDB(vocab=vocab, bits=jnp.asarray(ub), weights=jnp.asarray(uw),
-                       n_rows=len(transactions), n_classes=n_classes)
+                       n_rows=len(transactions), n_classes=n_classes,
+                       weight_bound=weight_sum_bound(uw))
 
     @staticmethod
     def from_arrays(vocab: ItemVocab, bits, weights, n_rows: int,
@@ -113,7 +120,8 @@ class DenseDB:
         uploads host arrays to device without re-encoding."""
         return DenseDB(vocab=vocab, bits=jnp.asarray(bits),
                        weights=jnp.asarray(weights), n_rows=n_rows,
-                       n_classes=n_classes)
+                       n_classes=n_classes,
+                       weight_bound=weight_sum_bound(weights))
 
     def project(self, keep_items: Sequence[Item]) -> "DenseDB":
         """Column projection + re-dedup (GFP data reduction, dense form)."""
@@ -121,7 +129,8 @@ class DenseDB:
         proj, sub = project_columns(bits_np, self.vocab, keep_items)
         ub, uw = dedup_rows(proj, np.asarray(self.weights))
         return DenseDB(vocab=sub, bits=jnp.asarray(ub), weights=jnp.asarray(uw),
-                       n_rows=self.n_rows, n_classes=self.n_classes)
+                       n_rows=self.n_rows, n_classes=self.n_classes,
+                       weight_bound=weight_sum_bound(uw))
 
 
 def dense_gfp_counts(
@@ -299,7 +308,7 @@ def minority_report_dense(
         stream = True
     else:
         est = n_db * 4 * (max(1, (len(items_kept) + 31) // 32) + 2)
-        stream = est > DEFAULT_STREAM_THRESHOLD_BYTES
+        stream = est > device_stream_threshold_bytes()
     if stream:
         db = StreamingDB.encode(db_list, classes=y01, n_classes=2, vocab=vocab,
                                 chunk_rows=chunk_rows)

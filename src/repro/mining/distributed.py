@@ -32,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..kernels.itemset_count import itemset_counts
+from ..kernels.itemset_count.ops import checked_accum, weight_sum_bound
 from .encode import ItemVocab, encode_targets
 
 Item = Hashable
@@ -71,7 +71,7 @@ def _count_shard_fn(mesh: Mesh, data_axes: Tuple[str, ...],
         out_shardings=NamedSharding(mesh, out_spec),
     )
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(tx_spec, tgt_spec, w_spec), out_specs=out_spec,
         check_vma=False,  # pallas_call out_shape carries no vma annotation
     )
@@ -83,14 +83,18 @@ def _count_shard_fn(mesh: Mesh, data_axes: Tuple[str, ...],
     return count_shard
 
 
-def _resolve_shard_config(n_local: int, k_local: int, w: int, c: int):
+def _resolve_shard_config(n_local: int, k_local: int, w: int, c: int,
+                          weights, weight_bound: Optional[int] = None):
     """Per-DEVICE launch config for a sharded launch: the table is keyed on
     the geometry each device actually sees (its local row/target block), not
-    the global problem."""
+    the global problem.  The mxu_f32 weight-sum bound is checked here, on
+    ``weight_bound`` or else the whole ``weights`` (which bound every
+    device's share): inside the shard_map trace they are abstract."""
     from ..roofline import autotune
     cfg = autotune.resolve_launch_config(max(1, n_local), max(1, k_local),
                                          max(1, w), max(1, c))
-    return cfg.block_k, cfg.block_n, cfg.accum
+    return cfg.block_k, cfg.block_n, checked_accum(None, cfg.accum, weights,
+                                                   weight_bound)
 
 
 def distributed_counts(
@@ -125,8 +129,8 @@ def distributed_counts(
     n, c = weights.shape
     # counts are bounded by the per-class weight-column sums; guard BEFORE any
     # device work — the kernel and psum run in int32 and would wrap silently
-    if n and np.any(np.asarray(weights).sum(axis=0, dtype=np.int64)
-                    > np.iinfo(np.int32).max):
+    bound = weight_sum_bound(weights)
+    if bound > np.iinfo(np.int32).max:
         raise OverflowError("per-class weight totals exceed int32; counts "
                             "could wrap — split the DB")
     dsize = int(np.prod([mesh.shape[a] for a in data_axes]))
@@ -142,7 +146,8 @@ def distributed_counts(
         n_pad = _round_up(chunk_rows, dsize)
         count_shard = _count_shard_fn(
             mesh, tuple(data_axes), model_axis, use_kernel,
-            *_resolve_shard_config(n_pad // dsize, k_pad // msize, w, c))
+            *_resolve_shard_config(n_pad // dsize, k_pad // msize, w, c,
+                                   weights, bound))
         tgt_d = jnp.asarray(tgt_p)
         txc = np.zeros((n_pad, tx_bits.shape[1]), np.uint32)
         wc = np.zeros((n_pad, c), np.int32)
@@ -173,7 +178,8 @@ def distributed_counts(
     n_pad = _round_up(max(n, 1), dsize)
     count_shard = _count_shard_fn(
         mesh, tuple(data_axes), model_axis, use_kernel,
-        *_resolve_shard_config(n_pad // dsize, k_pad // msize, w, c))
+        *_resolve_shard_config(n_pad // dsize, k_pad // msize, w, c,
+                               weights, bound))
     tx_p = np.zeros((n_pad, tx_bits.shape[1]), np.uint32)
     tx_p[:n] = tx_bits
     w_p = np.zeros((n_pad, c), np.int32)
@@ -219,6 +225,7 @@ def resident_distributed_counts(
     data_axes: Tuple[str, ...] = ("data",),
     model_axis: Optional[str] = None,
     use_kernel: bool = True,
+    weight_bound: Optional[int] = None,
 ) -> np.ndarray:              # (K, C) int32
     """:func:`distributed_counts` for a RESIDENT row placement: every device
     counts its local rows, one psum all-reduces the small (K, C) block.
@@ -227,7 +234,8 @@ def resident_distributed_counts(
     serving analogue of the resident ``DenseDB``); only the target block is
     padded and uploaded per call.  The int32 overflow guard is the CALLER's
     contract — a serving store guards its per-class row totals on every
-    append, before rows ever reach the placement."""
+    append, before rows ever reach the placement.  Its totals are also the
+    ``weight_bound`` that spares the mxu_f32 check a gather of ``w_dev``."""
     k, w = tgt_bits.shape
     c = int(w_dev.shape[1])
     if k == 0:
@@ -240,7 +248,7 @@ def resident_distributed_counts(
     count_shard = _count_shard_fn(
         mesh, tuple(data_axes), model_axis, use_kernel,
         *_resolve_shard_config(int(tx_dev.shape[0]) // dsize,
-                               k_pad // msize, w, c))
+                               k_pad // msize, w, c, w_dev, weight_bound))
     out = np.asarray(count_shard(tx_dev, jnp.asarray(tgt_p), w_dev))
     return np.array(out[:k], np.int32)
 

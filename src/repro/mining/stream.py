@@ -46,8 +46,22 @@ from .plan import choose_chunk_rows, stream_chunks
 
 Item = Hashable
 
-# Auto-select streaming when the encoded DB exceeds this device footprint.
+# The residency threshold where the backend reports no memory limit (CPU).
 DEFAULT_STREAM_THRESHOLD_BYTES = 512 << 20
+
+
+def device_stream_threshold_bytes() -> int:
+    """The one source of the dense-vs-streaming residency threshold: an
+    encoded DB over it streams from the host.  An eighth of the local
+    device's memory where the backend reports a limit, else
+    :data:`DEFAULT_STREAM_THRESHOLD_BYTES`.  A tuned table may scale it up
+    to twice this (``roofline.autotune.derived_chooser_thresholds``), which
+    keeps a resident base under a quarter of the memory: a count launch pads
+    and transposes the resident rows and a compaction builds the new base
+    beside the old one."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) // 8 if limit else DEFAULT_STREAM_THRESHOLD_BYTES
 
 
 def _pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:

@@ -99,9 +99,10 @@ def kernel_efficiency(snap: Optional[dict] = None) -> dict:
 
     ``{geometry: {launches, measured_s, predicted_s, efficiency}}`` where
     ``efficiency = predicted / measured`` — 1.0 means the launch ran at the
-    roofline model's bound for the TARGET hardware; far below 1.0 on this
-    CPU/interpret container is expected (the trend, not the absolute, is
-    the signal there).  Geometries come from the per-launch recording in
+    roofline model's bound for the chip it ran on.  On a device kind the
+    peaks table (``roofline.peaks``) does not know — the CPU among them —
+    no prediction is recorded: ``predicted_s`` is None and so is
+    ``efficiency``.  Geometries come from the per-launch recording in
     ``kernels/itemset_count/ops.py`` via ``roofline.kernel_model``."""
     snap = snap if snap is not None else snapshot()
     launches = snap.get("counters", {}).get("kernel_launches_total", {})
@@ -111,12 +112,12 @@ def kernel_efficiency(snap: Optional[dict] = None) -> dict:
     for ls, n in launches.items():
         geom = ls.replace("geometry=", "", 1) if ls else ""
         m = measured.get(ls, 0.0)
-        p = predicted.get(ls, 0.0)
+        p = predicted.get(ls)
         out[geom] = {
             "launches": int(n),
             "measured_s": m,
             "predicted_s": p,
-            "efficiency": (p / m) if m > 0 else None,
+            "efficiency": (p / m) if p is not None and m > 0 else None,
         }
     return out
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 
 
 def pipeline_forward(
@@ -42,7 +41,7 @@ def pipeline_forward(
     out_spec = P(None, batch_axis)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_specs, x_spec), out_specs=out_spec,
         check_vma=False,
     )

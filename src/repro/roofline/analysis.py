@@ -1,7 +1,7 @@
 """Roofline analysis from a compiled dry-run artifact.
 
 Three terms per (arch × shape × mesh) cell, all in seconds-per-step on the
-TARGET hardware (TPU v5e-class constants; this container only compiles):
+TARGET hardware (the TPU v5e entry of ``roofline.peaks``; compiled only):
 
   compute    = HLO_FLOPs_per_device            / PEAK_FLOPS
   memory     = HLO_bytes_accessed_per_device   / HBM_BW
@@ -21,9 +21,12 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-# --- target hardware constants (TPU v5e-class, per chip) --------------------
-PEAK_FLOPS = 197e12      # bf16 FLOP/s
-HBM_BW = 819e9           # bytes/s
+from .peaks import PEAKS
+
+# --- target hardware constants (the TPU v5e entry of roofline.peaks) -------
+_TARGET = PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _TARGET.bf16_flops
+HBM_BW = _TARGET.hbm_bytes_per_s
 LINK_BW = 50e9           # bytes/s per ICI link
 
 _DTYPE_BYTES = {

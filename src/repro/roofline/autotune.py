@@ -7,7 +7,7 @@ loop the ROADMAP autotuning item asks for:
 
   * **offline sweep** (:func:`sweep`, driven by ``tools/autotune.py``):
     micro-benchmark the candidate lattice — ``block_k ∈ {64,128,256,512}``,
-    ``accum ∈ {vpu_int32, mxu_f32}`` (the N < 2^24 exactness guard is
+    ``accum ∈ {vpu_int32, mxu_f32}`` (the 2^24 weight-sum bound is
     respected: oversized geometries never get an MXU candidate), and a
     ``chunk_rows`` grid for the streaming sweep — over bucketized launch
     geometries, and persist the winner per (device-kind, geometry-bucket)
@@ -16,10 +16,9 @@ loop the ROADMAP autotuning item asks for:
     that used to hard-code ``block_k=256`` / ``accum="vpu_int32"`` /
     ``chunk_rows`` heuristics now passes ``None`` and lets this function
     look the geometry's bucket up in the active table — falling back to
-    the original defaults when there is no table, no matching entry, or an
-    entry whose ``mxu_f32`` pick would violate the exactness bound for the
-    actual row count.  Resolution happens EAGERLY (host-side, concrete
-    shapes) so jit caches always see concrete static arguments.
+    the original defaults when there is no table or no matching entry.
+    Resolution happens EAGERLY (host-side, concrete shapes) so jit caches
+    always see concrete static arguments.
   * **online staleness** (:func:`staleness_report`): the live per-bucket
     efficiency ledger is compared against the sweep-time efficiency of the
     recorded runner-up candidate; a tuned entry whose measured ratio
@@ -50,12 +49,12 @@ import math
 import os
 import re
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 from .. import obs
-from .kernel_model import (GEOMETRY_OVERFLOW, bucket_shape, geometry_bucket,
-                           predicted_seconds)
+from .kernel_model import bucket_shape, geometry_bucket, predicted_seconds
+from .peaks import local_peaks
 
 __all__ = [
     "LaunchConfig", "TuningTable", "TableEntry", "TableError",
@@ -79,7 +78,9 @@ BLOCK_K_LATTICE = (64, 128, 256, 512)
 ACCUM_LATTICE = ("vpu_int32", "mxu_f32")
 CHUNK_ROWS_GRID = (0, 4096, 16384)      # 0 = the staging-budget heuristic
 
-# mxu_f32 is exact only while every launch sees < 2^24 rows (ops.py guard).
+# The sweep's synthetic problems carry unit weights, so a launch's per-class
+# weight sum is its row count: mxu_f32 candidates stop at 2^24 rows (the
+# ops.py weight-sum bound).
 MXU_MAX_ROWS = 1 << 24
 
 # The serve seam's reference micro-batch: the batcher pads each flush's K up
@@ -173,14 +174,15 @@ _STATE = {"pinned": False, "resolved": False, "table": None}
 
 
 def device_kind() -> str:
-    """Normalized device-kind token for table file names ('cpu', 'tpu_v5e'…)."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception as e:
-        _note_fallback("device_kind", e)
-        return "cpu"
-    return re.sub(r"[^a-z0-9_.-]+", "_", str(kind).lower()).strip("_") or "cpu"
+    """Normalized device-kind token for table file names ('cpu',
+    'tpu_v5_lite'…).  A device that cannot be asked raises: answering 'cpu'
+    would load the CPU table's launch configs on an accelerator."""
+    import jax
+    kind = str(jax.devices()[0].device_kind)
+    token = re.sub(r"[^a-z0-9_.-]+", "_", kind.lower()).strip("_")
+    if not token:
+        raise ValueError(f"device kind {kind!r} names no tuning table")
+    return token
 
 
 def repo_table_path(kind: Optional[str] = None) -> str:
@@ -249,10 +251,9 @@ def resolve_launch_config(n: int, k: int, w: int, c: int) -> LaunchConfig:
     """Launch config for one (N, K, W, C) geometry: the active table's entry
     for its bucket, or :data:`DEFAULT_CONFIG`.
 
-    Exactness guard re-checked at resolve time: a table entry tuned to
-    ``mxu_f32`` on a bucket whose ACTUAL row count reaches 2^24 falls back
-    to the VPU accumulator (buckets round up, so a tuned bucket can be hit
-    by a larger real N than the sweep measured)."""
+    An ``mxu_f32`` pick is re-checked against the launch's actual weights
+    in ``kernels/itemset_count/ops.py``: a launch whose per-class weight
+    sum reaches 2^24 runs on the exact VPU accumulator instead."""
     t = active_table()
     if t is None:
         _M_RESOLVE_DEFAULT.inc()
@@ -261,11 +262,8 @@ def resolve_launch_config(n: int, k: int, w: int, c: int) -> LaunchConfig:
     if entry is None:
         _M_RESOLVE_DEFAULT.inc()
         return DEFAULT_CONFIG
-    cfg = entry.config
-    if cfg.accum == "mxu_f32" and n >= MXU_MAX_ROWS:
-        cfg = replace(cfg, accum=DEFAULT_ACCUM)
     _M_RESOLVE_TABLE.inc()
-    return cfg
+    return entry.config
 
 
 def resolve_serve_block_k(store) -> int:
@@ -348,8 +346,9 @@ def table_from_dict(doc: dict, source: str = "<memory>") -> TuningTable:
         if bk not in BLOCK_K_LATTICE:
             raise TableError(f"{bucket}: block_k {bk!r} outside the lattice "
                              f"{BLOCK_K_LATTICE}")
-        if not isinstance(bn, int) or bn <= 0:
-            raise TableError(f"{bucket}: block_n must be a positive int")
+        if not isinstance(bn, int) or bn <= 0 or bn % 128:
+            raise TableError(f"{bucket}: block_n must be a positive "
+                             "multiple of 128 (the lane width)")
         if accum not in ACCUM_LATTICE:
             raise TableError(f"{bucket}: accum {accum!r} outside "
                              f"{ACCUM_LATTICE}")
@@ -464,6 +463,9 @@ def sweep(geometries: Iterable[Tuple[int, int, int, int]], *,
             buckets.append(b)
 
     entries: Dict[str, TableEntry] = {}
+    # sweep-time efficiency needs this chip's peaks; an unknown kind (the
+    # CPU among them) records 0.0 — no ratio against another chip's peak
+    peaks = local_peaks()
     prev_timing = obs.KERNEL_TIMING
     obs.configure(kernel_timing=False)
     try:
@@ -545,7 +547,8 @@ def sweep(geometries: Iterable[Tuple[int, int, int, int]], *,
                                     accum=win_acc, chunk_rows=win_cr or None,
                                     source="table"),
                 us=us,
-                efficiency=predicted_seconds(n, k, w, c) / (us * 1e-6),
+                efficiency=(predicted_seconds(n, k, w, c, peaks)
+                            / (us * 1e-6) if peaks is not None else 0.0),
                 candidates=cands,
                 chunk_candidates=chunk_cands,
                 serve_block_k=serve_bk or None,
@@ -716,9 +719,8 @@ def derived_chooser_thresholds(
         out["min_depth"] = min(8, max(2, round(4 - shift)))
     rho = _stream_ratio(t)
     if rho is not None:
-        from ..mining.stream import DEFAULT_STREAM_THRESHOLD_BYTES
-        scaled = int(DEFAULT_STREAM_THRESHOLD_BYTES / (2 * max(rho, 0.25)))
-        out["stream_threshold_bytes"] = min(
-            2 * DEFAULT_STREAM_THRESHOLD_BYTES,
-            max(DEFAULT_STREAM_THRESHOLD_BYTES // 2, scaled))
+        from ..mining.stream import device_stream_threshold_bytes
+        base = device_stream_threshold_bytes()
+        scaled = int(base / (2 * max(rho, 0.25)))
+        out["stream_threshold_bytes"] = min(2 * base, max(base // 2, scaled))
     return out

@@ -12,24 +12,24 @@ contained pairs.  Per launch of geometry (N, K, W, C):
                                             (integer ops priced as FLOPs at
                                             the VPU's int32 lane rate)
 
-Predicted launch time on the TARGET hardware is the perfect-overlap roofline
-bound ``max(bytes/HBM_BW, flops/PEAK_FLOPS)`` with the same TPU v5e-class
-constants as ``roofline.analysis``.  ``record_launch`` publishes measured
-wall time against that prediction into the telemetry registry
-(``repro.obs``) so ``CountServer.stats()`` / the Prometheus export report a
-measured-vs-predicted **efficiency ratio** per geometry.
-
-Container caveat: this repo's CI box runs the kernel in Pallas interpret
-mode on CPU, so absolute efficiency there is tiny and only the TREND across
-commits is meaningful; on a real TPU the ratio is the MFU-style signal the
-autotuning ROADMAP item keys on.
+Predicted launch time on the chip the launch ran on is the perfect-overlap
+roofline bound ``max(bytes/HBM_BW, flops/PEAK_FLOPS)`` with that chip's
+entry in ``roofline.peaks`` (looked up by ``device_kind``).  No VPU integer
+rate is published, so the op count is priced at the bf16 MXU peak — an
+optimistic bound.  ``record_launch`` publishes measured wall time against
+that prediction into the telemetry registry (``repro.obs``) so
+``CountServer.stats()`` / the Prometheus export report a
+measured-vs-predicted **efficiency ratio** per geometry.  A device kind the
+peaks table does not know (the CPU among them) records launches and
+measured time but no prediction, so it reports no ratio.
 """
 from __future__ import annotations
 
+import functools
 import re
-from typing import Tuple
+from typing import Optional, Tuple
 
-from .analysis import HBM_BW, PEAK_FLOPS
+from .peaks import ChipPeaks, local_peaks
 
 _WORD_BYTES = 4
 
@@ -46,11 +46,17 @@ def kernel_bytes(n: int, k: int, w: int, c: int) -> float:
 
 
 def predicted_seconds(n: int, k: int, w: int, c: int,
-                      peak_flops: float = PEAK_FLOPS,
-                      hbm_bw: float = HBM_BW) -> float:
-    """Perfect-overlap roofline bound for one launch on target hardware."""
-    return max(kernel_flops(n, k, w, c) / peak_flops,
-               kernel_bytes(n, k, w, c) / hbm_bw)
+                      peaks: ChipPeaks) -> float:
+    """Perfect-overlap roofline bound for one launch on the chip ``peaks``
+    describes."""
+    return max(kernel_flops(n, k, w, c) / peaks.bf16_flops,
+               kernel_bytes(n, k, w, c) / peaks.hbm_bytes_per_s)
+
+
+@functools.lru_cache(maxsize=1)
+def _launch_peaks() -> Optional[ChipPeaks]:
+    """Peaks of the device the launches run on (fixed for the process)."""
+    return local_peaks()
 
 
 def geometry_label(n: int, k: int, w: int, c: int) -> str:
@@ -125,11 +131,14 @@ def record_launch(n: int, k: int, w: int, c: int, seconds: float) -> None:
     ``repro.obs.kernel_efficiency``.  The prediction uses the exact
     geometry; only the aggregation label is bucketized (bounded label set,
     and the same keys the tuning table uses — closing the feedback loop in
-    ``roofline.autotune.staleness_report``)."""
+    ``roofline.autotune.staleness_report``).  On a device kind without
+    peaks the prediction counter is left alone, so no ratio is derived."""
     from ..obs import REGISTRY
 
     geom = _bucket_label(n, k, w, c)
     REGISTRY.counter("kernel_launches_total", geometry=geom).inc()
     REGISTRY.counter("kernel_measured_s_total", geometry=geom).inc(seconds)
-    REGISTRY.counter("kernel_predicted_s_total", geometry=geom).inc(
-        predicted_seconds(n, k, w, c))
+    peaks = _launch_peaks()
+    if peaks is not None:
+        REGISTRY.counter("kernel_predicted_s_total", geometry=geom).inc(
+            predicted_seconds(n, k, w, c, peaks))

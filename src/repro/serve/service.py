@@ -41,6 +41,7 @@ import numpy as np
 from .. import obs
 from ..core.fpgrowth import mine_frequent
 from ..core.incremental import ceil_count, incremental_candidates
+from ..mining.encode import transaction_lists
 from ..obs import REGISTRY, TRACER
 from .async_loop import AsyncFlusher, CountFuture
 from .batcher import MicroBatcher, build_masks, canonical_itemset
@@ -329,7 +330,7 @@ class CountServer:
         with self._lock, \
                 TRACER.span("serve.append",
                             {"n_rows": len(transactions)}) as sp:
-            transactions = [list(t) for t in transactions]
+            transactions = transaction_lists(transactions)
             old_version = self.store.version
             version = self.store.append(transactions, classes=classes)
             sp.set("version", version)

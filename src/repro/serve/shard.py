@@ -43,7 +43,9 @@ Two all-reduce paths
   ``resident_distributed_counts`` launch — each device counts its local rows
   and a psum all-reduces the (K, C) block.  This is the
   ``mining/distributed.py`` composition: serving rides the exact same
-  shard_map counting launch mining uses.
+  shard_map counting launch mining uses.  The shards themselves stay
+  host-resident (streaming) unless ``streaming=False`` is given, so the
+  device memory holds each row once, in the placement.
 
 Mining over a sharded store goes through :class:`ShardedCountBackend` — the
 :class:`~repro.mining.backend.CountBackend` with one checkpoint chunk PER
@@ -58,7 +60,8 @@ from typing import Hashable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..mining.backend import CountBackend
-from ..mining.encode import ItemVocab, extend_vocab, pad_words
+from ..mining.encode import (ItemVocab, extend_vocab, pad_words,
+                             transaction_lists)
 from ..obs import REGISTRY
 from .store import VersionedDB, check_class_labels, counts_for_itemsets
 
@@ -98,7 +101,7 @@ class ShardedDB:
     ):
         if n_shards <= 0:
             raise ValueError("n_shards must be positive")
-        transactions = [list(t) for t in transactions]
+        transactions = transaction_lists(transactions)
         if classes is not None and len(classes) != len(transactions):
             # validate BEFORE partitioning: the round-robin slice would
             # silently drop surplus labels (after they widened n_classes)
@@ -115,6 +118,11 @@ class ShardedDB:
         self._mesh_resident = None   # (bits, weights) device placement, lazy
         # one GLOBAL vocab; every shard starts from it (prefix invariant)
         self.vocab = ItemVocab.from_transactions(transactions)
+        if mesh is not None and streaming is None:
+            # on a mesh the rows the queries count live in the row placement
+            # over all devices; the shards keep theirs on the host, or every
+            # shard base would sit on the default device beside the placement
+            streaming = True
         self.shards: List[VersionedDB] = []
         for s in range(n_shards):
             part = list(range(s, len(transactions), n_shards))  # round-robin
@@ -185,7 +193,7 @@ class ShardedDB:
     ) -> int:
         """Route the batch to the least-loaded shard; bump ONE logical
         version.  A rejected batch leaves no trace on any shard."""
-        transactions = [list(t) for t in transactions]
+        transactions = transaction_lists(transactions)
         if not transactions:
             return self.version
         # validate + guard against the GLOBAL totals before any shard state
@@ -272,7 +280,8 @@ class ShardedDB:
                                    int(bits_d.shape[1]))
             got = resident_distributed_counts(
                 bits_d, narrow, w_d, self.mesh, data_axes=self.data_axes,
-                model_axis=None, use_kernel=self.use_kernel)
+                model_axis=None, use_kernel=self.use_kernel,
+                weight_bound=int(self._class_totals.max(initial=0)))
             self._mesh_launches += 1
             _M_SWEEP_MESH.inc()
             return got

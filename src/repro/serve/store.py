@@ -49,7 +49,8 @@ from ..mining.backend import CountBackend
 from ..obs import REGISTRY, TRACER
 from ..mining.dense import DenseDB
 from ..mining.encode import (ItemVocab, class_weights, dedup_rows,
-                             encode_bitmap, extend_vocab, pad_words)
+                             encode_bitmap, extend_vocab, pad_words,
+                             transaction_lists, vocab_and_bitmap)
 from ..mining.spill import (DEFAULT_SPILL_THRESHOLD_BYTES, SpilledDB,
                             spilled_counts)
 from ..mining.stream import StreamingDB, streaming_counts
@@ -154,10 +155,12 @@ class VersionedDB:
         # (None when residency was explicitly forced by the caller)
         self.backend_choice = None
 
-        transactions = [list(t) for t in transactions]
-        self.vocab = vocab if vocab is not None else \
-            ItemVocab.from_transactions(transactions)
-        ub, uw = self._encode_batch(transactions, classes)
+        transactions = transaction_lists(transactions)
+        bits = None
+        if vocab is None:
+            vocab, bits = vocab_and_bitmap(transactions)
+        self.vocab = vocab
+        ub, uw = self._encode_batch(transactions, classes, bits=bits)
         self._class_totals = self._guard_totals(
             self._class_totals + uw.sum(axis=0, dtype=np.int64))
         self.n_rows = len(transactions)
@@ -172,6 +175,13 @@ class VersionedDB:
             self._compactor.close()
             self._compactor = None
 
+    def _weight_bound(self) -> int:
+        """Bound on any segment's per-class weight sum, for the kernel's
+        mxu_f32 check: the guarded class totals cover base and delta alike
+        (multiplicities are non-negative), so no launch reads its device
+        weights back to the host."""
+        return int(self._class_totals.max(initial=0))
+
     @staticmethod
     def _guard_totals(totals: np.ndarray) -> np.ndarray:
         # largest possible count = per-class weight-column total; the int32
@@ -183,7 +193,7 @@ class VersionedDB:
         return totals
 
     # -- encode ---------------------------------------------------------------
-    def _encode_batch(self, transactions, classes, vocab=None):
+    def _encode_batch(self, transactions, classes, vocab=None, bits=None):
         if classes is None or len(transactions) == 0:
             if self.n_classes != 1 and len(transactions):
                 # ones in EVERY class column would count each row per class
@@ -195,8 +205,9 @@ class VersionedDB:
             if len(classes) != len(transactions):
                 raise ValueError("classes length != transactions length")
             w = class_weights(classes, self.n_classes)
-        bits = encode_bitmap(transactions,
-                             self.vocab if vocab is None else vocab)
+        if bits is None:
+            bits = encode_bitmap(transactions,
+                                 self.vocab if vocab is None else vocab)
         return dedup_rows(bits, w)
 
     def _spill_threshold_resolved(self) -> Optional[int]:
@@ -342,7 +353,7 @@ class VersionedDB:
         ``merge_ratio`` compaction threshold folds it into the base.
         An empty batch is a no-op (version unchanged: no count can differ).
         """
-        transactions = [list(t) for t in transactions]
+        transactions = transaction_lists(transactions)
         if not transactions:
             return self.version
         t0 = time.perf_counter()
@@ -538,7 +549,8 @@ class VersionedDB:
                 else:
                     got = np.asarray(itemset_counts(
                         self.base.bits, jnp.asarray(narrow), self.base.weights,
-                        use_kernel=self.use_kernel, **bk))
+                        use_kernel=self.use_kernel,
+                        weight_bound=self._weight_bound(), **bk))
                     self.kernel_launches += 1
                 total += self._zero_oob(got, oob)
             # delta segment (bounded by merge_ratio * base_rows: one launch);
@@ -552,7 +564,8 @@ class VersionedDB:
                 d_bits, d_weights = self._delta_device
                 got = np.asarray(itemset_counts(
                     d_bits, jnp.asarray(narrow), d_weights,
-                    use_kernel=self.use_kernel, **bk))
+                    use_kernel=self.use_kernel,
+                    weight_bound=self._weight_bound(), **bk))
                 self.kernel_launches += 1
                 total += self._zero_oob(got, oob)
         return total
@@ -720,7 +733,8 @@ class VersionedCountBackend(CountBackend):
                 else:
                     got = np.asarray(itemset_counts(
                         store.base.bits, jnp.asarray(narrow),
-                        store.base.weights, use_kernel=store.use_kernel))
+                        store.base.weights, use_kernel=store.use_kernel,
+                        weight_bound=store._weight_bound()))
                     store.kernel_launches += 1
                     total = total + store._zero_oob(got, oob)
                     if on_chunk is not None:
@@ -733,7 +747,8 @@ class VersionedCountBackend(CountBackend):
                 d_bits, d_weights = store._delta_device
                 got = np.asarray(itemset_counts(
                     d_bits, jnp.asarray(narrow), d_weights,
-                    use_kernel=store.use_kernel))
+                    use_kernel=store.use_kernel,
+                    weight_bound=store._weight_bound()))
                 store.kernel_launches += 1
                 total = total + store._zero_oob(got, oob)
                 if on_chunk is not None:

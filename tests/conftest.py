@@ -1,6 +1,15 @@
 """Shared test config: ``--runslow`` gating for slow tests + seeded RNG."""
+import os
+
 import numpy as np
 import pytest
+
+# tests (and the launchers they start) run on the CPU, where the Pallas
+# kernel runs in interpret mode: a chip, where there is one, stays free for
+# chip_smoke.py.  They keep JAX's persistent compilation cache off, so
+# nothing a test compiles is written into the checkout.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 
 def pytest_addoption(parser):

@@ -140,6 +140,7 @@ def test_table_json_roundtrip(tmp_path):
     lambda d: d["entries"]["n1024_k256_w2_c2"].update(chunk_rows=-1),
     lambda d: d["entries"]["n1024_k256_w2_c2"].update(us=0),
     lambda d: d["entries"]["n1024_k256_w2_c2"].update(serve_block_k=100),
+    lambda d: d["entries"]["n1024_k256_w2_c2"].update(block_n=1000),
 ])
 def test_table_schema_rejection(mutate):
     doc = table_to_dict(_mk_table({"n1024_k256_w2_c2": _entry()}))
@@ -212,15 +213,24 @@ def test_resolve_hits_matching_bucket_and_misses_fall_back():
 
 
 def test_resolve_mxu_guard_falls_back_to_vpu():
-    # the 2^26 N-clamp buckets huge row counts together: an mxu_f32 entry
-    # tuned there must not leak to an actual N >= 2^24 launch
-    n_big = 1 << 25
-    bucket = geometry_bucket(n_big, 8, 1, 1)
+    # resolution hands out the tuned entry as it is; the exactness guard
+    # runs at launch, on the launch's weights: a class weight sum of 2^24
+    # over a handful of rows must not reach the f32 accumulator
+    from repro.kernels.itemset_count import itemset_counts, ops
+
+    bucket = geometry_bucket(8, 8, 1, 1)
     autotune.set_active_table(_mk_table({bucket: _entry(accum="mxu_f32",
                                                         block_k=64)}))
-    cfg = resolve_launch_config(n_big, 8, 1, 1)
-    assert cfg.accum == "vpu_int32"      # guard applied
-    assert cfg.block_k == 64             # rest of the entry kept
+    cfg = resolve_launch_config(8, 8, 1, 1)
+    assert (cfg.accum, cfg.block_k) == ("mxu_f32", 64)
+    tx = np.full((8, 1), 1, np.uint32)
+    masks = np.ones((8, 1), np.uint32)
+    heavy = np.full((8, 1), 1 << 21, np.int32)           # sum == 2^24
+    assert not ops.mxu_f32_exact(heavy)
+    assert ops.checked_accum(None, cfg.accum, heavy) == "vpu_int32"
+    assert ops.checked_accum(None, cfg.accum, heavy // 2) == "mxu_f32"
+    got = np.asarray(itemset_counts(tx, masks, heavy))
+    assert (got == 1 << 24).all()
 
 
 def test_resolve_serve_block_k_uses_store_geometry():
@@ -392,13 +402,14 @@ def _throughput_table(overhead_us=100.0, per_row_us=0.05, rho=1.0):
 
 
 def test_derived_thresholds_scale_with_measured_overhead():
-    from repro.mining.stream import DEFAULT_STREAM_THRESHOLD_BYTES
+    from repro.mining.stream import device_stream_threshold_bytes
 
+    residency = device_stream_threshold_bytes()
     base = autotune.derived_chooser_thresholds(_throughput_table())
     assert base["tiny_rows"] == 2000          # overhead / per_row
     assert base["min_depth"] == 4             # overhead == reference
     assert base["gfp_host_rows"] == 4096      # floored at the hybrid default
-    assert base["stream_threshold_bytes"] == DEFAULT_STREAM_THRESHOLD_BYTES // 2
+    assert base["stream_threshold_bytes"] == residency // 2
 
     pricey = autotune.derived_chooser_thresholds(
         _throughput_table(overhead_us=400.0))
@@ -412,8 +423,7 @@ def test_derived_thresholds_scale_with_measured_overhead():
     # residency threshold; free chunking (rho ~ 2) lowers it
     slow_chunk = autotune.derived_chooser_thresholds(
         _throughput_table(rho=0.25))
-    assert slow_chunk["stream_threshold_bytes"] == \
-        2 * DEFAULT_STREAM_THRESHOLD_BYTES
+    assert slow_chunk["stream_threshold_bytes"] == 2 * residency
 
     assert autotune.derived_chooser_thresholds(_mk_table({})) == {}
     autotune.set_active_table(None)
@@ -448,7 +458,8 @@ def test_sweep_smoke_produces_valid_winning_table(tmp_path):
     assert set(t.entries) == {geometry_bucket(256, 16, 1, 1)}
     e = t.entries[geometry_bucket(256, 16, 1, 1)]
     assert e.config.block_k in (128, 256)
-    assert e.us > 0 and e.efficiency > 0
+    # the CPU has no entry in the peaks table: no efficiency ratio
+    assert e.us > 0 and e.efficiency == 0.0
     assert set(e.candidates) == {"bk128/vpu_int32", "bk256/vpu_int32"}
     # k=16 can't shrink under any candidate block — no serve view
     assert e.serve_block_k is None and e.serve_candidates == {}
@@ -457,18 +468,39 @@ def test_sweep_smoke_produces_valid_winning_table(tmp_path):
     assert load_table(path).entries.keys() == t.entries.keys()
 
 
-def test_sweep_serve_view_prefers_less_padding():
-    """The serve view times each candidate at k = block_k (the batcher pads
-    a flush up to the block), so the small block's 4x-less-work launch must
-    win the padded-flush comparison — the structural effect the fixed-K
-    candidates cannot see."""
-    t = autotune.sweep([(16384, 256, 2, 2)], repeats=2,
-                       block_ks=(64, 256), accums=("vpu_int32",),
-                       chunk_grid=(0,), kind="testkind")
-    e = t.entries[geometry_bucket(16384, 256, 2, 2)]
+def test_sweep_serve_view_prefers_less_padding(monkeypatch):
+    """The serve view launches each candidate at k = block_k (the batcher
+    pads a flush up to the block), so for the reference batch the small
+    block's padded flush does 4x less containment work than the default
+    block's — the structural effect the fixed-K candidates cannot see.
+    Asserted on the launched geometry, not on a CPU timing."""
+    import repro.kernels.itemset_count as kic
+
+    launched = []
+    real = kic.itemset_counts
+
+    def recording(tx, tgt, wts, **kw):
+        launched.append((int(tx.shape[0]), int(tgt.shape[0]),
+                         kw["block_k"]))
+        return real(tx, tgt, wts, **kw)
+
+    monkeypatch.setattr(kic, "itemset_counts", recording)
+    n = 2048
+    t = autotune.sweep([(n, 256, 2, 2)], repeats=1, block_ks=(64, 256),
+                       accums=("vpu_int32",), chunk_grid=(0,),
+                       kind="testkind")
+    e = t.entries[geometry_bucket(n, 256, 2, 2)]
     assert set(e.serve_candidates) == {"64", "256"}
-    assert e.serve_candidates["64"] < e.serve_candidates["256"]
-    assert e.serve_block_k == 64
+    assert all(bk in BLOCK_K_LATTICE for bk in (64, 256))
+    # contained pairs per launch = rows x padded targets
+    work = {}
+    for rows, k, bk in launched:
+        if k == bk:                      # the serve view: k = block_k
+            work[bk] = rows * k
+    flushes = {bk: -(-autotune.SERVE_REF_BATCH // bk) for bk in (64, 256)}
+    padded = {bk: flushes[bk] * work[bk] for bk in (64, 256)}
+    assert padded[64] * 4 == padded[256]
+    assert padded[64] == n * autotune.SERVE_REF_BATCH
 
 
 def test_sweep_leaves_telemetry_clean():

@@ -55,8 +55,8 @@ def test_kernel_raw_layout_exact_blocks():
     rng = np.random.default_rng(3)
     tx, tgt, wts = _random_problem(rng, 512, 64, 4, 2)
     got = itemset_counts_pallas(tx.T, tgt, wts.T, block_k=32, block_n=128,
-                                interpret=True)
-    want = itemset_counts_ref(tx, tgt, wts).T
+                                interpret=True)               # (K, C)
+    want = itemset_counts_ref(tx, tgt, wts)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -206,13 +206,122 @@ def test_mxu_f32_exact_near_2p24_bound():
 
 
 def test_mxu_f32_row_bound_raises_value_error():
-    """N >= 2^24 rows per launch must be rejected (ops.py exactness guard);
-    the streaming engine re-establishes the bound per chunk instead.  A real
-    ValueError with the geometry — not a bare assert that ``python -O``
-    strips — and raised BEFORE any device work."""
+    """2^24 unit-weight rows in one launch put the class weight sum at the
+    f32 bound: an explicit mxu_f32 request is rejected (ops.py exactness
+    guard); the streaming engine re-establishes the bound per chunk
+    instead.  A real ValueError with the weights' shape — not a bare assert
+    that ``python -O`` strips — and raised BEFORE any device work."""
     n = 1 << 24
     tx = jnp.zeros((n, 1), jnp.uint32)
     tgt = jnp.zeros((1, 1), jnp.uint32)
     w = jnp.ones((n, 1), jnp.int32)
-    with pytest.raises(ValueError, match=r"N < 2\^24.*N=16777216"):
+    with pytest.raises(ValueError, match=r"< 2\^24.*\(16777216, 1\)"):
         itemset_counts(tx, tgt, w, accum="mxu_f32")
+
+
+@pytest.mark.parametrize("weight", [257, 4099, (1 << 16) + 1, (1 << 23) - 3])
+def test_mxu_f32_exact_for_weights_above_bf16(weight):
+    """Dedup multiplicities above 2^8 are not bf16-representable: the MXU
+    path must not round them (explicit HIGHEST precision).  Odd weights
+    make any rounding visible."""
+    rng = np.random.default_rng(weight)
+    tx, tgt, _ = _random_problem(rng, 300, 20, 2, 2)
+    wts = np.ones((300, 2), np.int32)
+    wts[:1, 0] = weight                   # class-0 sum stays < 2^24
+    wts = jnp.asarray(wts)
+    got = itemset_counts(tx, tgt, wts, accum="mxu_f32", block_k=8,
+                         block_n=128)
+    want = itemset_counts_ref(tx, tgt, wts)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_mxu_f32_guard_on_weight_sum_over_few_rows():
+    """A deduped store can hold few rows with huge multiplicities: 4 rows of
+    weight 2^22 + 1 sum past 2^24 although N is tiny.  An explicit mxu_f32
+    request raises; a tuned mxu_f32 pick falls back to the exact VPU path."""
+    from repro.roofline import autotune
+    from repro.roofline.autotune import (LaunchConfig, TableEntry,
+                                         TuningTable)
+    from repro.roofline.kernel_model import geometry_bucket
+
+    tx = jnp.asarray(np.full((4, 1), 0xFFFFFFFF, np.uint32))
+    tgt = jnp.asarray(np.array([[1], [3]], np.uint32))
+    wts = jnp.asarray(np.full((4, 1), (1 << 22) + 1, np.int32))
+    with pytest.raises(ValueError, match=r"< 2\^24"):
+        itemset_counts(tx, tgt, wts, accum="mxu_f32")
+    bucket = geometry_bucket(4, 2, 1, 1)
+    autotune.set_active_table(TuningTable("cpu", {bucket: TableEntry(
+        LaunchConfig(block_k=64, accum="mxu_f32", source="table"), us=1.0,
+        efficiency=0.0)}))
+    try:
+        got = itemset_counts(tx, tgt, wts)
+    finally:
+        autotune.set_active_table(None)
+    assert np.asarray(got).tolist() == [[4 * ((1 << 22) + 1)]] * 2
+
+
+def test_mxu_f32_guard_on_the_mesh_launch():
+    """The sharded launch traces the kernel inside shard_map, where the
+    weights are abstract: a tuned mxu_f32 pick must still be checked
+    against the weight sum before tracing.  An odd class total just past
+    2^24 has no f32 representation, so an unchecked f32 sum would be off."""
+    import jax
+
+    from repro.mining.distributed import (place_rows,
+                                          resident_distributed_counts)
+    from repro.roofline import autotune
+    from repro.roofline.autotune import LaunchConfig, TableEntry, TuningTable
+    from repro.roofline.kernel_model import geometry_bucket
+
+    mesh = jax.make_mesh((1,), ("data",))
+    tx = np.full((4, 1), 0xFFFFFFFF, np.uint32)
+    wts = np.full((4, 1), (1 << 22) + 1, np.int32)
+    wts[3] += 1                                  # total 2^24 + 5, odd
+    tgt = np.array([[1], [3]], np.uint32)
+    bits_d, w_d = place_rows(tx, wts, mesh)
+    autotune.set_active_table(TuningTable("cpu", {
+        geometry_bucket(4, 2, 1, 1): TableEntry(
+            LaunchConfig(block_k=64, accum="mxu_f32", source="table"),
+            us=1.0, efficiency=0.0)}))
+    try:
+        got = resident_distributed_counts(bits_d, tgt, w_d, mesh)
+    finally:
+        autotune.set_active_table(None)
+    assert np.asarray(got).tolist() == [[(1 << 24) + 5]] * 2
+
+
+def test_mxu_f32_bound_comes_from_the_weights_owner(monkeypatch):
+    """A store, a DenseDB and a mesh placement check a tuned mxu_f32 pick
+    against the weight bound they keep, never by reading their device
+    weights back: with the host read made to fail, the counts still come
+    out, equal to the default path's."""
+    import jax
+
+    from repro.kernels.itemset_count import ops
+    from repro.mining.backend import DenseBackend
+    from repro.mining.encode import encode_targets
+    from repro.roofline import autotune
+    from repro.serve import ShardedDB, VersionedDB
+
+    tx = [[0, 1], [1, 2], [0, 1, 2], [2]] * 8
+    y = [0, 1] * 16
+    store = VersionedDB(tx, classes=y, n_classes=2, merge_ratio=1e9)
+    store.append([[0, 1, 2]] * 3, classes=[1, 1, 0])
+    meshed = ShardedDB(tx, classes=y, n_classes=2, n_shards=2,
+                       mesh=jax.make_mesh((1,), ("data",)))
+    backend = DenseBackend(store.base)
+    probes = [(0,), (1, 2), (0, 1, 2)]
+    masks = encode_targets(probes, store.vocab)
+    want = (store.counts(probes), meshed.counts(probes),
+            backend.counts(masks))
+
+    def no_host_read(weights):
+        raise AssertionError("the mxu_f32 check read the weights back")
+
+    monkeypatch.setattr(ops, "weight_sum_bound", no_host_read)
+    monkeypatch.setattr(autotune, "resolve_launch_config",
+                        lambda *a: autotune.LaunchConfig(accum="mxu_f32",
+                                                         source="table"))
+    np.testing.assert_array_equal(store.counts(probes), want[0])
+    np.testing.assert_array_equal(meshed.counts(probes), want[1])
+    np.testing.assert_array_equal(backend.counts(masks), want[2])

@@ -271,9 +271,9 @@ def test_server_stats_expose_kernel_efficiency(rng):
     for geom, rec in eff.items():
         assert rec["launches"] >= 1
         assert rec["measured_s"] > 0
-        assert rec["predicted_s"] > 0
-        assert rec["efficiency"] == pytest.approx(
-            rec["predicted_s"] / rec["measured_s"])
+        # the CPU is not in the peaks table: no prediction, no ratio
+        assert rec["predicted_s"] is None
+        assert rec["efficiency"] is None
         assert geom.startswith("n")
     snap = tele["metrics"]
     assert counter_value(snap, "serve_requests_total") == 1

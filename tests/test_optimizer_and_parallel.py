@@ -69,8 +69,7 @@ def test_compress_grads_tree_modes():
 
 # ---------------------------------------------------------------- sharding
 def test_pspec_rules_and_divisibility():
-    from repro.compat import abstract_mesh
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     # divisible dims keep their axes
     spec = shd.pspec(("embed", "ffn"), shape=(64, 128), mesh=mesh)
     assert spec == jax.sharding.PartitionSpec("data", "model")
