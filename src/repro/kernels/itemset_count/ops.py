@@ -16,7 +16,7 @@ import numpy as np
 
 from ... import obs
 from ...roofline import autotune
-from ...roofline.kernel_model import record_launch
+from ...roofline.kernel_model import count_launch, record_launch
 from .kernel import itemset_counts_pallas
 from .ref import itemset_counts_ref, itemset_counts_ref_blocked
 
@@ -125,28 +125,8 @@ def itemset_counts(
     if not use_kernel or w > MAX_KERNEL_WORDS:
         return itemset_counts_ref_blocked(tx_bits, tgt_bits, weights)
 
-    requested = accum
-    if block_k is None or block_n is None or accum is None:
-        # Eager host-side resolution (n/k/w/c are concrete Python ints even
-        # under a jit trace) so any jit cache downstream keys on the CONCRETE
-        # tuned values — never on a None that could alias across table swaps.
-        cfg = autotune.resolve_launch_config(n, k, w, c)
-        block_k = cfg.block_k if block_k is None else block_k
-        block_n = cfg.block_n if block_n is None else block_n
-        accum = cfg.accum if accum is None else accum
-    accum = checked_accum(requested, accum, weights, weight_bound)
-
-    # Shrink blocks for small problems, keeping TPU-friendly minima.
-    block_n = min(block_n, _round_up(n, 128))
-    block_k = min(block_k, _round_up(k, 8))
-
-    n_pad = _round_up(n, block_n) - n
-    k_pad = _round_up(k, block_k) - k
-    tx_p = jnp.pad(tx_bits, ((0, n_pad), (0, 0)))        # pad rows: weight 0
-    wt_p = jnp.pad(weights, ((0, n_pad), (0, 0)))
-    tgt_p = jnp.pad(tgt_bits, ((0, k_pad), (0, 0)))       # pad targets: sliced
-
-    # Per-launch telemetry: wall time vs the roofline model's prediction for
+    # Per-launch telemetry: the call's parts as child spans of
+    # ``kernel.count``, and wall time vs the roofline model's prediction for
     # this geometry (repro.obs / roofline.kernel_model).  Only measurable at
     # the eager boundary — under a jit trace (e.g. the streaming
     # itemset_counts_into step) the operands are Tracers and host timing
@@ -154,28 +134,94 @@ def itemset_counts(
     eager = (not isinstance(tx_bits, jax.core.Tracer)
              and not isinstance(tgt_bits, jax.core.Tracer))
     timed = obs.kernel_timing_enabled() and eager
-    span = (obs.TRACER.span("kernel.count",
-                            {"n": n, "k": k, "w": w, "c": c})
-            if eager else obs.tracing.NOOP_SPAN)
-    with span:
-        t0 = time.perf_counter() if timed else 0.0
-        out = itemset_counts_pallas(
-            tx_p.T, tgt_p, wt_p.T.astype(jnp.int32),
-            block_k=block_k, block_n=block_n, interpret=interpret,
-            accum=accum,
-        )                                                 # (K_pad, C)
+    span = obs.TRACER.span if eager else _no_span
+    with span("kernel.count", {"n": n, "k": k, "w": w, "c": c}):
+        with span("kernel.prepare"):
+            requested = accum
+            if block_k is None or block_n is None or accum is None:
+                # Eager host-side resolution (n/k/w/c are concrete Python
+                # ints even under a jit trace) so any jit cache downstream
+                # keys on the CONCRETE tuned values — never on a None that
+                # could alias across table swaps.
+                cfg = autotune.resolve_launch_config(n, k, w, c)
+                block_k = cfg.block_k if block_k is None else block_k
+                block_n = cfg.block_n if block_n is None else block_n
+                accum = cfg.accum if accum is None else accum
+            accum = checked_accum(requested, accum, weights, weight_bound)
+
+            # Shrink blocks for small problems, keeping TPU-friendly minima.
+            block_n = min(block_n, _round_up(n, 128))
+            block_k = min(block_k, _round_up(k, 8))
+
+            n_pad = _round_up(n, block_n) - n
+            k_pad = _round_up(k, block_k) - k
+            # pad rows get weight 0; pad targets are sliced off
+            tx_p = jnp.pad(tx_bits, ((0, n_pad), (0, 0)))
+            wt_p = jnp.pad(weights, ((0, n_pad), (0, 0)))
+            tgt_p = jnp.pad(tgt_bits, ((0, k_pad), (0, 0)))
+            tx_t = tx_p.T
+            wt_t = wt_p.T.astype(jnp.int32)
+        with span("kernel.launch"):
+            t0 = time.perf_counter() if timed else 0.0
+            out = itemset_counts_pallas(
+                tx_t, tgt_p, wt_t,
+                block_k=block_k, block_n=block_n, interpret=interpret,
+                accum=accum,
+            )                                             # (K_pad, C)
         if timed:
             # blocking gives a TRUE wall time; free on CPU (callers
             # materialize the counts immediately) but serializes a pipelined
             # TPU launch stream — obs.configure(kernel_timing=False) when
             # overlap matters
-            out.block_until_ready()
-            record_launch(n, k, w, c, time.perf_counter() - t0)
+            with span("kernel.wait"):
+                out.block_until_ready()
+            elapsed = time.perf_counter() - t0
+        if eager:
+            # every eager launch is counted; its time only when timed
+            with span("kernel.record"):
+                if timed:
+                    record_launch(n, k, w, c, elapsed)
+                else:
+                    count_launch(n, k, w, c)
     return out[:k, :]
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def _no_span(name: str, attrs: Optional[dict] = None):
+    """The tracer's disabled span, for the parts of a launch under a jit
+    trace: nothing is recorded there."""
+    return obs.tracing.NOOP_SPAN
+
+
+# ---------------------------------------------------------------------------
+# Process telemetry that needs JAX (``repro.obs`` imports only the stdlib):
+# the program's spans on a recorded profile's host plane, and a count of the
+# compilations JAX makes, each a ``jax.compile`` span under the span open on
+# the compiling thread while tracing is on.
+# ---------------------------------------------------------------------------
+
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_M_COMPILES = {stage: obs.REGISTRY.counter("jax_compiles_total", stage=stage)
+               for stage in _COMPILE_STAGES.values()}
+
+
+def _on_compile_event(event: str, seconds: float, **_kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    _M_COMPILES[stage].inc()
+    if obs.TRACER.enabled:
+        obs.TRACER.retro("jax.compile", seconds, {"stage": stage})
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+obs.tracing.set_host_annotation(jax.profiler.TraceAnnotation)
 
 
 # ---------------------------------------------------------------------------

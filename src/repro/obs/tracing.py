@@ -22,17 +22,36 @@ Tracing is OFF by default (the ring buffer and per-span objects are real
 allocations); ``tracer.enabled = True`` (or ``repro.obs.configure``) turns
 it on.  When disabled, ``span()`` returns a shared no-op singleton without
 allocating — the same zero-overhead contract as the metrics registry.
+
+While tracing is on, every span also enters the host annotation installed
+by :func:`set_host_annotation` (``jax.profiler.TraceAnnotation``, installed
+by the count op's module): in a recorded JAX profile the spans then sit on
+the profiler's host plane, on the same clock as the device's operations.
+This package imports only the stdlib, so the JAX side installs the hook.
 """
 from __future__ import annotations
 
+import copy
 import itertools
 import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 DEFAULT_RING_SPANS = 16384
+
+# Context-manager factory, called with a span's name, that every span also
+# enters while its tracer is on (None: spans stay in the ring alone).
+_host_annotation: Optional[Callable[[str], object]] = None
+
+
+def set_host_annotation(factory: Optional[Callable[[str], object]]) -> None:
+    """Install (or, with None, remove) the host annotation every span
+    enters: ``jax.profiler.TraceAnnotation`` puts spans on a recorded
+    profile's host plane."""
+    global _host_annotation
+    _host_annotation = factory
 
 
 class _NoopSpan:
@@ -49,6 +68,9 @@ class _NoopSpan:
     def set(self, key: str, value) -> None:
         return None
 
+    def end(self) -> None:
+        return None
+
 
 NOOP_SPAN = _NoopSpan()
 
@@ -57,7 +79,7 @@ class Span:
     """One timed region; finished spans are immutable ring entries."""
 
     __slots__ = ("tracer", "name", "span_id", "parent_id", "tid",
-                 "t0", "t1", "attrs")
+                 "t0", "t1", "attrs", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[dict] = None):
@@ -69,20 +91,31 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.attrs: Dict[str, object] = dict(attrs) if attrs else {}
+        self._annotation = None
 
     def set(self, key: str, value) -> None:
         self.attrs[key] = value
+
+    def end(self) -> None:
+        """Close a span opened by :meth:`Tracer.begin`."""
+        self.__exit__(None, None, None)
 
     def __enter__(self) -> "Span":
         stack = self.tracer._stack()
         if stack:
             self.parent_id = stack[-1].span_id
         stack.append(self)
+        if _host_annotation is not None:
+            self._annotation = _host_annotation(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         stack = self.tracer._stack()
@@ -105,11 +138,19 @@ class Tracer:
         self._ids = itertools.count(1)
         self._local = threading.local()
         self._epoch = time.perf_counter()
+        # every live thread's span stack, so a read sees the spans still open
+        self._stacks: Dict[int, List[Span]] = {}
+        self._stacks_lock = threading.Lock()
 
     def _stack(self) -> List[Span]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+            alive = {t.ident for t in threading.enumerate()}
+            with self._stacks_lock:
+                for ident in [i for i in self._stacks if i not in alive]:
+                    del self._stacks[ident]
+                self._stacks[threading.get_ident()] = stack
         return stack
 
     def span(self, name: str, attrs: Optional[dict] = None):
@@ -120,15 +161,35 @@ class Tracer:
             return NOOP_SPAN
         return Span(self, name, attrs)
 
+    def begin(self, name: str, attrs: Optional[dict] = None):
+        """Open a span now and return it; ``.end()`` closes it.  For a
+        region a ``with`` block cannot enclose, such as the wait for a lock
+        that a ``with`` statement takes."""
+        return self.span(name, attrs).__enter__()
+
     def instant(self, name: str, attrs: Optional[dict] = None) -> None:
         """Zero-duration marker (e.g. one submit): a span with t0 == t1."""
         if not self.enabled:
             return
+        now = time.perf_counter()
+        self._finished(name, attrs, now, now)
+
+    def retro(self, name: str, seconds: float,
+              attrs: Optional[dict] = None) -> None:
+        """A span that ends now and lasted ``seconds``, under the span open
+        on this thread: for work reported when it is over (a compile)."""
+        if not self.enabled:
+            return
+        now = time.perf_counter()
+        self._finished(name, attrs, now - seconds, now)
+
+    def _finished(self, name: str, attrs: Optional[dict], t0: float,
+                  t1: float) -> None:
         s = Span(self, name, attrs)
         stack = self._stack()
         if stack:
             s.parent_id = stack[-1].span_id
-        s.t0 = s.t1 = time.perf_counter()
+        s.t0, s.t1 = t0, t1
         self._ring.append(s)
 
     def reset(self) -> None:
@@ -136,8 +197,21 @@ class Tracer:
         self._epoch = time.perf_counter()
 
     def spans(self) -> List[Span]:
-        """Current ring contents, oldest first (a copy: stable to iterate)."""
-        return list(self._ring)
+        """Current ring contents, oldest first, then every span still open
+        on any thread, cut at now and marked ``open`` (a copy: stable to
+        iterate).  A thread parked inside a span, such as an idle flusher,
+        is then accounted for up to the read."""
+        out = list(self._ring)
+        now = time.perf_counter()
+        with self._stacks_lock:
+            stacks = list(self._stacks.values())
+        for stack in stacks:
+            for s in list(stack):
+                cut = copy.copy(s)
+                cut.t1, cut.attrs = now, dict(s.attrs, open=True)
+                cut._annotation = None
+                out.append(cut)
+        return out
 
     # -- export ---------------------------------------------------------------
     def chrome_trace(self) -> dict:

@@ -446,9 +446,9 @@ def sweep(geometries: Iterable[Tuple[int, int, int, int]], *,
     """Micro-benchmark the candidate lattice over each geometry's BUCKET and
     return the winning :class:`TuningTable` (not yet active or persisted).
 
-    Kernel wall-time telemetry is suspended for the duration: losing
-    candidates must not pollute the live efficiency ledger the staleness
-    rule reads."""
+    Metrics and kernel wall-time telemetry are suspended for the duration:
+    losing candidates must not pollute the live launch counts and
+    efficiency ledger the staleness rule reads."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -466,8 +466,8 @@ def sweep(geometries: Iterable[Tuple[int, int, int, int]], *,
     # sweep-time efficiency needs this chip's peaks; an unknown kind (the
     # CPU among them) records 0.0 — no ratio against another chip's peak
     peaks = local_peaks()
-    prev_timing = obs.KERNEL_TIMING
-    obs.configure(kernel_timing=False)
+    prev_metrics, prev_timing = obs.REGISTRY.enabled, obs.KERNEL_TIMING
+    obs.configure(metrics=False, kernel_timing=False)
     try:
         for bucket in buckets:
             n, k, w, c = bucket_shape(bucket)
@@ -560,7 +560,7 @@ def sweep(geometries: Iterable[Tuple[int, int, int, int]], *,
                     f"serve_block_k={serve_bk or 'default'}, "
                     f"{len(cands)} candidates)")
     finally:
-        obs.configure(kernel_timing=prev_timing)
+        obs.configure(metrics=prev_metrics, kernel_timing=prev_timing)
     return TuningTable(device_kind=kind or device_kind(), entries=entries,
                        created=created)
 

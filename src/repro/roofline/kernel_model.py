@@ -124,6 +124,16 @@ def _reset_geometry_buckets() -> None:
     _SEEN_BUCKETS.clear()
 
 
+def count_launch(n: int, k: int, w: int, c: int) -> str:
+    """Count one eager launch under its geometry bucket (with or without a
+    measured time); returns the bucket label."""
+    from ..obs import REGISTRY
+
+    geom = _bucket_label(n, k, w, c)
+    REGISTRY.counter("kernel_launches_total", geometry=geom).inc()
+    return geom
+
+
 def record_launch(n: int, k: int, w: int, c: int, seconds: float) -> None:
     """Publish one measured launch against the model: three counters per
     geometry BUCKET (launch count, measured seconds, predicted seconds) —
@@ -135,8 +145,7 @@ def record_launch(n: int, k: int, w: int, c: int, seconds: float) -> None:
     peaks the prediction counter is left alone, so no ratio is derived."""
     from ..obs import REGISTRY
 
-    geom = _bucket_label(n, k, w, c)
-    REGISTRY.counter("kernel_launches_total", geometry=geom).inc()
+    geom = count_launch(n, k, w, c)
     REGISTRY.counter("kernel_measured_s_total", geometry=geom).inc(seconds)
     peaks = _launch_peaks()
     if peaks is not None:

@@ -26,6 +26,11 @@ itself fails) the error.
 Thread safety: the owning ``CountServer`` serializes every state-touching
 operation (submit/flush/query/append/mine) behind one re-entrant lock when
 ``async_flush`` is enabled; the flusher piggybacks on that lock.
+
+Tracing: the flusher thread is inside a span at every moment — waiting for
+the server lock (``serve.lock_wait``), parked until a trigger
+(``serve.park``), flushing (``serve.flush``) or fulfilling futures
+(``serve.dispatch``) — so a device-idle gap always names a host step.
 """
 from __future__ import annotations
 
@@ -36,7 +41,7 @@ from typing import Dict, Hashable, Optional, Sequence
 
 import numpy as np
 
-from ..obs import REGISTRY, nearest_rank
+from ..obs import REGISTRY, TRACER, nearest_rank
 
 Item = Hashable
 
@@ -127,10 +132,11 @@ class AsyncFlusher:
                itemsets: Sequence[Sequence[Item]]) -> CountFuture:
         """Queue one request; returns its future.  Wakes the trigger thread
         when this submit starts the deadline clock or fills the batch."""
+        t_call = time.perf_counter()     # the wait for the lock starts here
         with self._server._lock:
             if self._closed:
                 raise RuntimeError("AsyncFlusher is closed")
-            ticket = self._server.batcher.submit(client_id, itemsets)
+            ticket = self._server.batcher.submit(client_id, itemsets, t_call)
             fut = CountFuture(ticket)
             self._futures[ticket] = fut
             first = self._oldest is None
@@ -151,37 +157,40 @@ class AsyncFlusher:
         flush START time: the recorded latency is the queue wait of the
         batch's oldest request — the quantity ``max_delay_ms`` bounds —
         not the wait plus the counting pass itself."""
-        if out:
-            now = started if started is not None else time.monotonic()
-            if self._oldest is not None:
-                wait_ms = (now - self._oldest) * 1e3
-                with self._lat_lock:
-                    self.latencies_ms.append(wait_ms)
-                _H_FLUSH_WAIT.observe(wait_ms)
-            self.n_flushes += 1
-            reason = self._reason or "manual"
-            self.flushes_by_trigger[reason] = \
-                self.flushes_by_trigger.get(reason, 0) + 1
-            REGISTRY.counter("serve_flushes_total", trigger=reason).inc()
-            for ticket, block in out.items():
-                fut = self._futures.pop(ticket, None)
-                if fut is not None:
-                    # a manual flush() caller receives the same blocks in its
-                    # return dict — the future gets its OWN copy, so neither
-                    # consumer can mutate the other's "exact" rows (the same
-                    # immutability contract the cache's defensive copy keeps)
-                    fut._set_result(np.array(block, np.int32, copy=True))
-                elif reason != "manual":
-                    # a synchronously submitted ticket drained by a
-                    # background (or drain) flush: its result must not
-                    # vanish — the next explicit flush() hands it back
-                    self._unclaimed[ticket] = block
-        # CountServer.flush calls _dispatch under the server lock (see the
-        # docstring): the lock IS held here, just not lexically visible
-        self._reason = None          # repro-lint: disable=CONC002
-        # repro-lint: disable=CONC002 -- caller holds the server lock
-        self._oldest = (None if self._server.batcher.pending == 0
-                        else time.monotonic())
+        with TRACER.span("serve.dispatch", {"n_tickets": len(out)}):
+            if out:
+                now = started if started is not None else time.monotonic()
+                if self._oldest is not None:
+                    wait_ms = (now - self._oldest) * 1e3
+                    with self._lat_lock:
+                        self.latencies_ms.append(wait_ms)
+                    _H_FLUSH_WAIT.observe(wait_ms)
+                self.n_flushes += 1
+                reason = self._reason or "manual"
+                self.flushes_by_trigger[reason] = \
+                    self.flushes_by_trigger.get(reason, 0) + 1
+                REGISTRY.counter("serve_flushes_total", trigger=reason).inc()
+                for ticket, block in out.items():
+                    fut = self._futures.pop(ticket, None)
+                    if fut is not None:
+                        # a manual flush() caller receives the same blocks
+                        # in its return dict — the future gets its OWN copy,
+                        # so neither consumer can mutate the other's "exact"
+                        # rows (the same immutability contract the cache's
+                        # defensive copy keeps)
+                        fut._set_result(np.array(block, np.int32, copy=True))
+                    elif reason != "manual":
+                        # a synchronously submitted ticket drained by a
+                        # background (or drain) flush: its result must not
+                        # vanish — the next explicit flush() hands it back
+                        self._unclaimed[ticket] = block
+            # CountServer.flush calls _dispatch under the server lock (see
+            # the docstring): the lock IS held here, just not lexically
+            # visible
+            self._reason = None          # repro-lint: disable=CONC002
+            # repro-lint: disable=CONC002 -- caller holds the server lock
+            self._oldest = (None if self._server.batcher.pending == 0
+                            else time.monotonic())
 
     def claim_unclaimed(self) -> Dict[int, np.ndarray]:
         """Hand back (and forget) results of sync tickets that a background
@@ -194,7 +203,9 @@ class AsyncFlusher:
         # lock between an escaping flush error and the handler would let a
         # concurrent manual flush() observe the stale _reason and
         # misclassify itself as a background trigger
+        waiting = TRACER.begin("serve.lock_wait")
         with self._server._lock:
+            waiting.end()
             if not self._server.batcher.pending:
                 return
             self._reason = reason
@@ -215,15 +226,16 @@ class AsyncFlusher:
 
     def _run(self) -> None:
         while True:
+            waiting = TRACER.begin("serve.lock_wait")
             with self._server._lock:
+                waiting.end()
                 if self._closed:
                     return
                 pending = self._server.batcher.pending
                 oldest = self._oldest
             now = time.monotonic()
             if now < self._backoff_until:
-                self._wake.wait(self._backoff_until - now)
-                self._wake.clear()
+                self._park(self._backoff_until - now)
                 continue
             if pending >= self.min_batch:
                 self._try_flush("occupancy")
@@ -234,8 +246,13 @@ class AsyncFlusher:
                 continue
             timeout = (None if oldest is None
                        else max(1e-4, oldest + self.max_delay_s - now))
+            self._park(timeout)
+
+    def _park(self, timeout: Optional[float]) -> None:
+        """Wait for a submit's wake-up or the timeout."""
+        with TRACER.span("serve.park"):
             self._wake.wait(timeout)
-            self._wake.clear()
+        self._wake.clear()
 
     # -- shutdown -------------------------------------------------------------
     def close(self) -> None:

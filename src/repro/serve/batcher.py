@@ -37,6 +37,13 @@ _M_REQUESTS = REGISTRY.counter("serve_requests_total")
 _M_QUERIES = REGISTRY.counter("serve_queries_total")
 _M_DEDUPED = REGISTRY.counter("serve_deduped_queries_total")
 _H_QUEUE_WAIT = REGISTRY.histogram("serve_queue_wait_ms")
+# the wait of a submit for the server's lock: finer buckets than the
+# default grid, since it runs up to one flush (tens of ms)
+_H_SUBMIT_WAIT = REGISTRY.histogram(
+    "serve_submit_wait_ms",
+    buckets=(0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 15.0, 20.0, 30.0,
+             40.0, 50.0, 60.0, 80.0, 100.0, 150.0, 250.0, 500.0, 1000.0,
+             2500.0))
 
 
 def canonical_itemset(itemset: Sequence[Item]) -> Key:
@@ -48,11 +55,14 @@ def canonical_itemset(itemset: Sequence[Item]) -> Key:
 @dataclass
 class QueryRequest:
     """One client's submitted query list (keys already canonical).
-    ``t_submit`` (perf_counter at submit) feeds the queue-wait histogram."""
+    ``t_submit`` (perf_counter at the enqueue) feeds the queue-wait
+    histogram; ``t_call`` (perf_counter at the call into the server's
+    submit, before its lock) the submit-wait one."""
     request_id: int
     client_id: str
     keys: List[Key]
     t_submit: float = 0.0
+    t_call: float = 0.0
 
 
 @dataclass
@@ -89,22 +99,28 @@ class MicroBatcher:
     def pending(self) -> int:
         return len(self._pending)
 
-    def submit(self, client_id: str, itemsets: Sequence[Sequence[Item]]) -> int:
-        """Queue one request; returns its ticket (the ``flush()`` result key)."""
+    def submit(self, client_id: str, itemsets: Sequence[Sequence[Item]],
+               t_call: Optional[float] = None) -> int:
+        """Queue one request; returns its ticket (the ``flush()`` result key).
+        ``t_call`` is when the caller asked to submit (before it waited for
+        its lock); None means now."""
         rid = self._next_id
         self._next_id += 1
         keys = [canonical_itemset(s) for s in itemsets]
-        self._pending.append(QueryRequest(rid, client_id, keys,
-                                          time.perf_counter()))
+        now = time.perf_counter()
+        t_call = now if t_call is None else t_call
+        self._pending.append(QueryRequest(rid, client_id, keys, now, t_call))
         self.n_requests += 1
         self.n_queries += len(keys)
         # instant (not a span): the queue wait is the flush's story, and
-        # cross-thread nesting would be fake — the ticket id is the link.
-        # Guarded so the disabled path allocates nothing (not even the
-        # attrs dict) per submit.
+        # cross-thread nesting would be fake — the ticket id is the link;
+        # a span on every client thread would also outlast the flusher's
+        # own as the latest-started one.  Guarded so the disabled path
+        # allocates nothing (not even the attrs dict) per submit.
         if TRACER.enabled:
             TRACER.instant("serve.submit",
-                           {"ticket": rid, "n_queries": len(keys)})
+                           {"ticket": rid, "n_queries": len(keys),
+                            "wait_ms": (now - t_call) * 1e3})
         return rid
 
     def take(self) -> BatchPlan:
@@ -129,6 +145,8 @@ class MicroBatcher:
             _M_DEDUPED.inc(dups)
         _H_QUEUE_WAIT.observe_many(
             [(now - req.t_submit) * 1e3 for req in self._pending])
+        _H_SUBMIT_WAIT.observe_many(
+            [(req.t_submit - req.t_call) * 1e3 for req in self._pending])
         plan = BatchPlan(unique_keys=unique, rows=rows,
                          requests=self._pending)
         self._pending = []

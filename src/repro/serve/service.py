@@ -178,8 +178,9 @@ class CountServer:
     def submit(self, client_id: str,
                itemsets: Sequence[Sequence[Item]]) -> int:
         """Queue one client request; returns the ticket ``flush()`` keys on."""
+        t_call = time.perf_counter()     # the wait for the lock starts here
         with self._lock:
-            return self.batcher.submit(client_id, itemsets)
+            return self.batcher.submit(client_id, itemsets, t_call)
 
     def submit_async(self, client_id: str,
                      itemsets: Sequence[Sequence[Item]]) -> CountFuture:
@@ -278,19 +279,21 @@ class CountServer:
         version = self.store.version
         resolved: Dict[Key, np.ndarray] = {}
         missing: List[Key] = []
-        for key in keys:
-            hit = self.cache.get(key, version) if self.cache is not None \
-                else None
-            if hit is not None:
-                resolved[key] = hit
-            else:
-                missing.append(key)
+        with TRACER.span("serve.cache_probe"):
+            for key in keys:
+                hit = self.cache.get(key, version) \
+                    if self.cache is not None else None
+                if hit is not None:
+                    resolved[key] = hit
+                else:
+                    missing.append(key)
         if missing:
             with TRACER.span("serve.count",
                              {"n_masks": len(missing), "version": version,
                               "cache_hits": len(keys) - len(missing)}):
-                masks, known = build_masks(missing, self.store.vocab,
-                                           self.batcher.block_k)
+                with TRACER.span("serve.masks"):
+                    masks, known = build_masks(missing, self.store.vocab,
+                                               self.batcher.block_k)
                 rows = self.store.counts_masks(
                     masks, block_k=self.batcher.block_k)[:len(missing)]
                 rows[~known] = 0     # unknown-item targets count exactly 0
@@ -299,9 +302,6 @@ class CountServer:
                     resolved[key] = row
                     if self.cache is not None:
                         self.cache.put(key, version, row)
-        elif keys:
-            TRACER.instant("serve.count_skipped",
-                           {"cache_hits": len(keys), "version": version})
         return resolved
 
     def query(self, itemsets: Sequence[Sequence[Item]],
@@ -313,13 +313,16 @@ class CountServer:
         at an older version."""
         with self._lock, \
                 TRACER.span("serve.query", {"n_itemsets": len(itemsets)}):
-            keys = [canonical_itemset(s) for s in itemsets]
-            resolved = self._resolve(list(dict.fromkeys(keys)))
+            with TRACER.span("serve.keys"):
+                keys = [canonical_itemset(s) for s in itemsets]
+                unique = list(dict.fromkeys(keys))
+            resolved = self._resolve(unique)
             self.n_queries_served += len(keys)
             if not keys:
                 return np.zeros((0, self.store.n_classes), np.int32)
-            return np.stack([resolved[k] for k in keys]).astype(np.int32,
-                                                                copy=False)
+            with TRACER.span("serve.reply"):
+                return np.stack([resolved[k] for k in keys]).astype(
+                    np.int32, copy=False)
 
     # -- growth path ----------------------------------------------------------
     def append(self, transactions: Sequence[Sequence[Item]],
