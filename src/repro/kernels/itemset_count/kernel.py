@@ -15,15 +15,28 @@ Layout rationale (TPU memory hierarchy):
     is revisited across the N sweep and accumulated in place (zeroed when
     n_idx == 0) — VMEM-resident accumulator, one HBM writeback per K tile;
   * inside a grid step a loop walks the K_b targets in sub-tiles of at most
-    32 rows; the containment test is an unrolled loop over the W words (W is
-    static and small — 32·W items), all in VREG-friendly elementwise uint32
-    ops (VPU).  The sub-tile bounds the live containment tile whatever the
+    32 rows.  The sub-tile bounds the live containment tile whatever the
     block sizes: a whole (K_b, N_b) tile at K_b=512, W=64 overran the 16 MiB
-    scoped VMEM, and compiled ten times slower where it fit.
+    scoped VMEM, and compiled ten times slower where it fit;
+  * containment is a residue over the W packed words (W static and small,
+    32·W items; the loop is unrolled): a row t holds target g iff
+    ``t | ~g`` is all ones in every word.  ``~g`` is taken once on the
+    (sub, W) target sub-tile; each word then costs one OR and one AND per
+    (sub, N_b) vreg on the VPU, and one compare against 0xFFFFFFFF follows
+    the word loop: W ORs, W - 1 ANDs and a compare, 2W ALU ops per vreg,
+    where ``(t & g) == g`` folded with a mask AND cost 3W - 1.  On a v5e
+    the loop is bound by vector-ALU issue, so this count sets the kernel's
+    pace;
+  * every word of every target is tested for every row, zero words and
+    all: no word skipping, no early exit, no containment on the MXU.  The
+    benchmark's roofline prices exactly this dense VPU work
+    (``n·k·(2w + c)`` ops), so a kernel that did less of it would read
+    above its own roofline.
 
 VMEM budget per grid step (W<=64, N_b=1024, K_b<=512, C<=8):
   tx (64,1024)·4B = 256KiB ; tgt (512,64)·4B = 128KiB ; w (8,1024)·4B = 32KiB ;
-  containment sub-tile (32,1024)·4B = 128KiB ; out (512,8)·4B = 16KiB.
+  ~tgt sub-tile (32,64)·4B = 8KiB ; residue sub-tile (32,1024)·4B = 128KiB ;
+  out (512,8)·4B = 16KiB.
 """
 from __future__ import annotations
 
@@ -61,14 +74,15 @@ def _itemset_count_kernel(tx_ref, tgt_ref, w_ref, out_ref, *, n_words: int,
 
     def sub_tile(i, carry):
         r0 = pl.multiple_of(i * sub, sub)
-        tgt = tgt_ref[pl.ds(r0, sub), :]               # (sub, W) uint32
-        # Containment: AND over the W packed words, unrolled (W static).
-        acc = None
+        # A row holds target g iff t | ~g is all ones in every word: one
+        # OR and one AND per word, one compare after the unrolled loop.
+        ng = ~tgt_ref[pl.ds(r0, sub), :]               # (sub, W) uint32
+        res = None
         for w in range(n_words):
             t_row = tx_ref[w, :]                       # (N_b,) uint32
-            g_col = tgt[:, w][:, None]                 # (sub, 1) uint32
-            hit = (t_row[None, :] & g_col) == g_col    # (sub, N_b) bool
-            acc = hit if acc is None else (acc & hit)
+            word = t_row[None, :] | ng[:, w][:, None]  # (sub, N_b) uint32
+            res = word if res is None else (res & word)
+        acc = res == jnp.uint32(0xFFFFFFFF)            # (sub, N_b) bool
         if accum == "mxu_f32":
             # (sub, N_b) x (C, N_b) -> (sub, C), contracting the lane axis
             part = jax.lax.dot_general(

@@ -325,3 +325,72 @@ def test_mxu_f32_bound_comes_from_the_weights_owner(monkeypatch):
     np.testing.assert_array_equal(store.counts(probes), want[0])
     np.testing.assert_array_equal(meshed.counts(probes), want[1])
     np.testing.assert_array_equal(backend.counts(masks), want[2])
+
+
+_ONES = np.uint32(0xFFFFFFFF)
+_BIT31 = np.uint32(1 << 31)
+
+
+def _edge_targets(w, rng, case):
+    """(K, W) targets for one edge case of the residue test t | ~g == ~0."""
+    tgt = np.zeros((64, w), np.uint32)
+    if case == "empty":                 # every word 0: every row holds it
+        tgt[1::2] = random_problem(rng, 1, 32, w, 1)[1]
+    elif case == "ones_bit31":          # all-ones words, bit 31 alone
+        tgt[0::4, rng.integers(0, w)] = _ONES
+        tgt[1::4, :] = _BIT31
+        tgt[2::4, 0] = _BIT31 | np.uint32(1)
+        tgt[3::4] = random_problem(rng, 1, 16, w, 1)[1]
+    elif case in ("items_pairs", "w1", "w64"):
+        for i in range(64):             # 1 and 2 items, in distinct words
+            first = int(rng.integers(0, 32 * w))
+            items = [first]
+            if i % 2 and w > 1:
+                other = int(rng.integers(0, w - 1))
+                other += other >= first >> 5
+                items.append(32 * other + int(rng.integers(0, 32)))
+            for item in items:
+                tgt[i, item >> 5] |= np.uint32(1) << np.uint32(item & 31)
+        tgt[5, :] = 0                   # the empty target among them
+    elif case == "unheld":              # bit 7 of word 0 is in no row
+        tgt[:] = random_problem(rng, 1, 64, w, 1)[1]
+        tgt[::3, 0] |= np.uint32(1 << 7)
+    return tgt
+
+
+@pytest.mark.parametrize("accum", ["vpu_int32", "mxu_f32"])
+@pytest.mark.parametrize("case,w", [
+    ("empty", 3),
+    ("ones_bit31", 4),
+    ("items_pairs", 32),               # the bulk cell's width
+    ("unheld", 5),
+    ("w1", 1),
+    ("w64", 64),
+])
+def test_residue_containment_edge_cases(case, w, accum):
+    """The kernel's word-wise residue (``t | ~g``, then one compare with
+    all ones) equals the jnp oracle at its edges: empty targets, all-ones
+    words and bit 31 on both sides, 1- and 2-item targets across words, a
+    target no row holds, W = 1 and W = 64.  Launched raw, so nothing in
+    ops.py can route round the kernel."""
+    rng = np.random.default_rng(sum(map(ord, case)) + w)
+    n = 512
+    tx = random_problem(rng, n, 1, w, 1, density=0.3)[0]
+    tx[::7] = _ONES                     # rows that hold every item
+    tx[1::11] = _BIT31                  # rows of bit-31 items only
+    tx[2::13] |= _BIT31
+    tx[3::17] = 0                       # empty rows
+    if case == "unheld":
+        tx[:, 0] &= ~np.uint32(1 << 7)
+    tgt = _edge_targets(w, rng, case)
+    wts = rng.integers(0, 5, size=(n, 2)).astype(np.int32)
+    got = itemset_counts_pallas(jnp.asarray(tx.T), jnp.asarray(tgt),
+                                jnp.asarray(wts.T), block_k=64, block_n=256,
+                                interpret=True, accum=accum)
+    want = np.asarray(itemset_counts_ref(jnp.asarray(tx), jnp.asarray(tgt),
+                                         jnp.asarray(wts)))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    if case == "empty":
+        np.testing.assert_array_equal(want[0], wts.sum(axis=0))
+    if case == "unheld":
+        assert not want[::3].any()
