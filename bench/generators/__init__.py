@@ -2,21 +2,18 @@
 
 A configuration file names its generator (``"generator": "bernoulli"``); the
 module ``bench/generators/<name>.py`` has ``generate(cfg, seed)``, which
-returns flat arrays: ``items`` (int16 item codes, row-major), ``row_ptr``
-(int64 row offsets), ``classes`` (int32), ``n_items`` and ``base_rows``;
-an item is its code.  The program is handed rows made by :func:`rows_between`; the
-references read the flat arrays.
+returns flat arrays: ``items`` (item codes ``0 .. n_items - 1``, row-major,
+of any integer type up to int32, so at most 2^31 items; a row holds each of
+its items once, ascending), ``row_ptr`` (int64 row offsets), ``classes``
+(int32), ``n_items`` and ``base_rows``; an item is its code.  The program
+is handed rows made by :func:`rows_between`; the references read the flat
+arrays, and widen the codes wherever they compute with them.
 """
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List, Tuple
 
 import numpy as np
-
-
-def load(name: str):
-    return importlib.import_module(f"bench.generators.{name}")
 
 
 def rows_between(data: Dict, lo: int, hi: int) -> Tuple[List[list],
@@ -28,10 +25,3 @@ def rows_between(data: Dict, lo: int, hi: int) -> Tuple[List[list],
     offs = (ptr[lo:hi + 1] - ptr[lo]).tolist()
     rows = list(map(flat.__getitem__, map(slice, offs[:-1], offs[1:])))
     return rows, data["classes"][lo:hi]
-
-
-def flat_pairs(data: Dict, hi: int) -> Tuple[np.ndarray, np.ndarray]:
-    """(row, item code) of every held cell of rows ``0`` to ``hi``."""
-    ptr = data["row_ptr"]
-    rows = np.repeat(np.arange(hi, dtype=np.int32), np.diff(ptr[:hi + 1]))
-    return rows, data["items"][:ptr[hi]].astype(np.int32)

@@ -10,7 +10,8 @@ of threads.
 
 The result is the flat arrays of ``bench.generators``; the first ``rows``
 rows are the base and the ``append_rows`` after them the append.  Items are
-the ints ``0 .. items - 1``, ascending inside a row.
+the ints ``0 .. items - 1``, ascending inside a row: int16 codes up to
+2^15 items, int32 above.
 """
 from __future__ import annotations
 
@@ -21,6 +22,10 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 BLOCK_ROWS = 131_072
+
+
+def _code_type(items: int) -> type:
+    return np.int16 if items <= 1 << 15 else np.int32
 
 
 def _block(seed: int, index: int, rows: int, items: int,
@@ -41,7 +46,7 @@ def _block(seed: int, index: int, rows: int, items: int,
     flat = np.concatenate(pos)
     flat = flat[:np.searchsorted(flat, cells)]
     row = flat // items
-    return ((flat - row * items).astype(np.int16),
+    return ((flat - row * items).astype(_code_type(items)),
             np.bincount(row, minlength=rows))
 
 
@@ -63,6 +68,6 @@ def generate(cfg: Dict, seed: int) -> Dict[str, np.ndarray]:
     classes = (np.random.default_rng([seed, 2]).random(total)
                < float(cfg["p_y"])).astype(np.int32)
     return {"items": (np.concatenate([a for a, _ in parts]) if parts
-                      else np.zeros(0, np.int16)),
+                      else np.zeros(0, _code_type(items))),
             "row_ptr": row_ptr, "classes": classes,
             "n_items": items, "base_rows": int(cfg["rows"])}

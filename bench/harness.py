@@ -5,9 +5,10 @@ configuration's file (``bench/configs/<config>.json``) names its data
 generator (``bench/generators/<generator>.py``); the mix's file
 (``bench/traffic/<traffic>.json``) names its driver
 (``bench/drivers/<driver>.py``).  Each per-layer metric is read by
-``bench/layer_metrics/<metric>.py`` (see :func:`reader_path`).  A later
-change adds a cell, a mix or a metric by adding such files and entries;
-this file finds them by name.
+``bench/layer_metrics/<metric>.py`` (see :func:`reader_path`), and the
+tests under ``bench/`` run a cell at the sizes of ``bench/tiny/<cell>.json``.
+A later change adds a cell, a mix or a metric by adding such files and
+entries; this file finds them by name, under the root it is given.
 
 A run: check the device, generate the data from the seed, let the driver
 build the program's own entry objects and warm every shape up (set-up),
@@ -17,7 +18,6 @@ what the window produced with the plain reference, and print the result.
 from __future__ import annotations
 
 import gc
-import importlib
 import importlib.util
 import json
 import os
@@ -81,8 +81,16 @@ def _module(path: str, name: str):
     return mod
 
 
-def load_driver(name: str):
-    return importlib.import_module(f"bench.drivers.{name}")
+def load_driver(name: str, root: str = ROOT):
+    """The traffic driver ``bench/drivers/<name>.py`` under ``root``."""
+    return _module(os.path.join(root, "bench", "drivers", f"{name}.py"),
+                   f"bench_driver_{name}")
+
+
+def load_generator(name: str, root: str = ROOT):
+    """The data generator ``bench/generators/<name>.py`` under ``root``."""
+    return _module(os.path.join(root, "bench", "generators", f"{name}.py"),
+                   f"bench_generator_{name}")
 
 
 def reader_path(metric: str, root: str = ROOT) -> str:
@@ -242,7 +250,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     import jax
 
-    from bench.generators import load as load_generator
     from repro.launch.compile_cache import enable_compile_cache
     from repro import obs
 
@@ -253,11 +260,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if overrides:
         cfg.update(overrides.get("config", {}))
         traffic.update(overrides.get("traffic", {}))
-    driver = load_driver(traffic["driver"])
+    driver = load_driver(traffic["driver"], root)
     counter = CompileCounter()
 
     with phase("generate"):
-        data = load_generator(cfg["generator"]).generate(cfg, seed)
+        data = load_generator(cfg["generator"], root).generate(cfg, seed)
     state = driver.setup(cfg, traffic, seed, data, seconds)
     if patch is not None:
         patch(state)

@@ -3,6 +3,8 @@
 An itemset of at most 4 items over at most 2^15 item codes is held as one
 int64 code (15 bits an item, ascending, empty slots all ones), so that
 distinct itemsets are found with ``np.unique`` and not with Python sets.
+Only the open-loop mix packs keys so: a serve mix over more than 32,767
+items needs the packing widened first.
 """
 from __future__ import annotations
 

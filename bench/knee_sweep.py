@@ -39,7 +39,6 @@ def main(argv=None) -> int:
 
     from bench import harness
     from bench.drivers import open_loop
-    from bench.generators import load as load_generator
     from repro.launch.compile_cache import enable_compile_cache
 
     bench = harness.load_benchmark()
@@ -52,7 +51,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     cfg = harness.load_config(bench, cell["config"])
     traffic = harness.load_traffic(cell["traffic"])
-    data = load_generator(cfg["generator"]).generate(cfg, args.seed)
+    data = harness.load_generator(cfg["generator"]).generate(cfg, args.seed)
     state = open_loop.setup(cfg, traffic, args.seed, data, args.seconds)
     harness.log(f"set-up {time.perf_counter() - T0:.1f} s")
     rows = []

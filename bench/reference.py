@@ -28,6 +28,37 @@ def _member(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return b[idx] == a
 
 
+def _pair_counts(rows: np.ndarray, items: np.ndarray, n_items: int,
+                 mc: int) -> Dict[tuple, int]:
+    """The pairs ``(a, b)``, ``a < b``, that at least ``mc`` rows hold,
+    with those counts, ascending.  ``rows`` and ``items`` are cells sorted
+    by row and, in a row, by item.  Each pair of a row is one code
+    ``a * n_items + b``: the cells ``d`` apart in a row, for d = 1, 2, ...
+    until no row is that long.  The codes are counted by ``np.bincount``
+    where a count per possible pair is no larger than the codes, and by
+    ``np.unique`` otherwise, so nothing of size ``n_items ** 2`` is made
+    for a wide vocabulary."""
+    n = int(n_items)
+    items = items.astype(np.int64)
+    codes = []
+    for d in range(1, rows.shape[0]):
+        same = rows[d:] == rows[:-d]
+        if not same.any():
+            break
+        codes.append(items[:-d][same] * n + items[d:][same])
+    codes = np.concatenate(codes) if codes else np.zeros(0, np.int64)
+    if codes.shape[0] >= n * n:
+        counts = np.bincount(codes, minlength=n * n)
+        keys = np.flatnonzero(counts >= mc)
+        counts = counts[keys]
+    else:
+        keys, counts = np.unique(codes, return_counts=True)
+        keep = counts >= mc
+        keys, counts = keys[keep], counts[keep]
+    a, b = np.divmod(keys, n)
+    return dict(zip(zip(a.tolist(), b.tolist()), counts.tolist()))
+
+
 class HostReference:
     """Per-class counts from one ascending row-id list per item."""
 
@@ -76,17 +107,11 @@ class HostReference:
                                    if c1[a] >= mc}
         if top < 2:
             return found
-        # pairs: the count of every pair at once, from the target rows
-        # (float32 sums of 0/1 are exact below 2^24 rows)
-        target_rows = np.flatnonzero(self.classes == target)
-        x1 = np.zeros((target_rows.shape[0], self.n_items), np.float32)
-        x1[np.searchsorted(target_rows, self.rows[hit]), self.items[hit]] = 1
-        pair_c1 = np.rint(x1.T @ x1).astype(np.int64)
-        del x1
-        a, b = np.triu_indices(self.n_items, 1)
-        keep = pair_c1[a, b] >= mc
-        level = dict(zip(zip(a[keep].tolist(), b[keep].tolist()),
-                         pair_c1[a[keep], b[keep]].tolist()))
+        # the target rows' cells by row, each row's items ascending (the
+        # stable sort keeps the item order the constructor made)
+        rows, items = self.rows[hit], self.items[hit]
+        by_row = np.argsort(rows, kind="stable")
+        level = _pair_counts(rows[by_row], items[by_row], self.n_items, mc)
         size = 2
         while level:
             found.update(level)

@@ -10,7 +10,7 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 import pytest  # noqa: E402
 
 from bench import control, harness  # noqa: E402
-from bench._tiny import SECONDS, TINY  # noqa: E402
+from bench._tiny import SECONDS, tiny_sizes  # noqa: E402
 
 CELLS = [c["name"] for c in harness.load_benchmark()["workloads"]]
 
@@ -20,7 +20,7 @@ CELLS = [c["name"] for c in harness.load_benchmark()["workloads"]]
 def test_broken_path_reads_not_correct(cell, fault):
     r = harness.run_cell(cell, 2**31 + 99, SECONDS, False,
                          t0=time.perf_counter(), require_tpu=False,
-                         overrides=TINY[cell],
+                         overrides=tiny_sizes(cell),
                          patch=getattr(control, fault))
     assert r["correct"] is False
     broken = [n for n, c in r["checks"].items() if c["value"] > c["limit"]]

@@ -105,3 +105,62 @@ def test_zipf_and_arrivals():
     t = K.poisson_arrivals(rng, 1000.0, 5.0)
     assert t.shape[0] == pytest.approx(5000, rel=0.1)
     assert np.all(np.diff(t) > 0) and t[-1] < 5.0
+
+
+def _wide(seed, rows=4000, items=40_000):
+    """Flat arrays over a vocabulary wider than int16 holds: rows of 1-12
+    items, half of them from 40 hot codes spread over the vocabulary (most
+    above 32,767), half uniform; the codes int32."""
+    rng = np.random.default_rng(seed)
+    hot = np.concatenate([rng.choice(32_768, 8, replace=False),
+                          32_768 + rng.choice(items - 32_768, 32,
+                                              replace=False)])
+    out = []
+    for _ in range(rows):
+        n = int(rng.integers(1, 13))
+        pick = np.where(rng.random(n) < 0.5, rng.choice(hot, n),
+                        rng.integers(0, items, n))
+        out.append(np.unique(pick))
+    row_ptr = np.zeros(rows + 1, np.int64)
+    np.cumsum([r.shape[0] for r in out], out=row_ptr[1:])
+    return {"items": np.concatenate(out).astype(np.int32),
+            "row_ptr": row_ptr,
+            "classes": (rng.random(rows) < 0.3).astype(np.int32),
+            "n_items": items, "base_rows": rows}, out, hot
+
+
+@pytest.mark.parametrize("seed", [21, 22, BIG_SEED])
+def test_wide_reference_equals_brute_force(seed):
+    """Codes above 2^15: the pair lister counts each row's pairs without
+    anything of size n_items^2, and equals a count over Python sets."""
+    import itertools
+    import tracemalloc
+    from collections import Counter
+
+    d, rows, hot = _wide(seed)
+    n = len(rows)
+    theta = 0.002
+    mc = reference.min_count(theta, n)
+    tracemalloc.start()
+    got = reference.minority_frequent(d, n, theta, 1, max_level=2)
+    peak = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
+    assert peak < 64 << 20          # an n_items^2 array is 1.6e9 cells
+    want = Counter()
+    for r, y in zip(rows, d["classes"].tolist()):
+        if y == 1:
+            r = r.tolist()
+            want.update((a,) for a in r)
+            want.update(itertools.combinations(r, 2))
+    assert got == {k: v for k, v in want.items() if v >= mc}
+    assert sum(len(k) == 2 for k in got) > 100
+    assert any(k[0] > 32_767 for k in got if len(k) == 2)
+
+    ref = reference.from_data(d, n)
+    sets = [tuple(sorted(hot[:2])), tuple(sorted(hot[-3:])), (int(hot[-1]),),
+            tuple(sorted(hot[[0, 20]]))]
+    y = d["classes"]
+    for s in sets:
+        holds = np.array([set(s) <= set(r.tolist()) for r in rows])
+        assert ref.counts(s, n).tolist() == \
+            np.bincount(y[holds], minlength=2).tolist()
