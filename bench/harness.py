@@ -253,6 +253,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from repro.launch.compile_cache import enable_compile_cache
     from repro import obs
 
+    from bench import target_words
+
     cache_dir = enable_compile_cache()
     log(f"compile cache: {cache_dir}")
     cfg = dict(load_config(bench, cell["config"], root))
@@ -274,7 +276,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     traced: Dict = {}
     counter.active = True
     setup_s = seconds_since_process_start(t0)
-    with profiled(trace, traced):
+    with profiled(trace, traced), target_words.recorded(state, trace):
         with jax.profiler.TraceAnnotation("bench.window"):
             t_open = time.perf_counter()
             observed = driver.window(state, seconds)

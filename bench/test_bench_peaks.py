@@ -24,13 +24,83 @@ def test_v5e_entry_and_int32_ceiling():
     assert p.source
 
 
-@pytest.mark.parametrize("n,k,w,c", [(4_040_000, 16_384, 32, 2),
-                                     (4_000_000, 256, 32, 2),
-                                     (22_500, 930, 4, 2), (1, 1, 1, 1)])
-def test_counts_equal_the_formulas(n, k, w, c):
-    assert roofline.kernel_ops(n, k, w, c) == n * k * (2 * w + c)
-    assert roofline.kernel_bytes(n, k, w, c) == \
+DENSE_SHAPES = [(4_040_000, 16_384, 32, 2), (4_000_000, 256, 32, 2),
+                (22_500, 930, 4, 2), (1, 1, 1, 1)]
+
+
+def _dense_before(n, k, w, c):
+    """The dense model's arithmetic as it stood before targets were priced
+    by the words they hold: ops, bytes, least time and its bound."""
+    p = PEAKS[V5E]
+    ops = float(n) * float(k) * (2.0 * w + c)
+    nbytes = 4.0 * (float(n) * w + float(n) * c + float(k) * w
+                    + float(k) * c)
+    t_ops, t_bytes = ops / p.int32_ops, nbytes / p.hbm_bytes_per_s
+    least = (t_ops, "ops") if t_ops >= t_bytes else (t_bytes, "bytes")
+    return ops, nbytes, least
+
+
+@pytest.mark.parametrize("words", ["default", "every word held"])
+@pytest.mark.parametrize("n,k,w,c", DENSE_SHAPES)
+def test_counts_equal_the_formulas(n, k, w, c, words):
+    """Targets that hold all ``w`` words (``held = k * w``, ``touched =
+    w``, given or left to the default) give exactly the dense formulas."""
+    extra = () if words == "default" else (k * w, w)
+    assert roofline.kernel_ops(n, k, w, c, *extra[:1]) == \
+        n * k * (2 * w + c)
+    assert roofline.kernel_bytes(n, k, w, c, *extra) == \
         4 * (n * w + n * c + k * w + k * c)
+
+
+@pytest.mark.parametrize("n,k,w,c", DENSE_SHAPES)
+def test_dense_targets_give_the_old_numbers_exactly(n, k, w, c):
+    p = PEAKS[V5E]
+    ops, nbytes, least = _dense_before(n, k, w, c)
+    assert roofline.kernel_ops(n, k, w, c, k * w) == ops
+    assert roofline.kernel_bytes(n, k, w, c, k * w, w) == nbytes
+    assert roofline.least_seconds(n, k, w, c, p, k * w, w) == least
+    assert roofline.least_seconds(n, k, w, c, p) == least
+    share = roofline.roofline_share([(n, k, w, c, k * w, w)], 1.0, p)
+    assert share == roofline.roofline_share([(n, k, w, c)], 1.0, p)
+    assert share == (100.0 * least[0], least[1])
+
+
+def test_pairs_in_two_words_by_hand():
+    """16,384 pairs over 4,040,000 rows of 32 words and 2 classes, each
+    pair's items in 2 words, the pairs touching every word: per row 2 x
+    32,768 word tests and 32,768 adds."""
+    p = PEAKS[V5E]
+    n, k, w, c = 4_040_000, 16_384, 32, 2
+    held, touched = 2 * k, 32
+    ops = 4_040_000 * (2 * 32_768 + 32_768)             # 3.971e11
+    nbytes = 4 * (4_040_000 * 32 + 4_040_000 * 2 + 32_768 + 32_768)
+    assert roofline.kernel_ops(n, k, w, c, held) == ops
+    assert roofline.kernel_bytes(n, k, w, c, held, touched) == nbytes
+    least, bound = roofline.least_seconds(n, k, w, c, p, held, touched)
+    assert bound == "ops" and least == pytest.approx(ops / 6.155e12,
+                                                     rel=1e-3)
+    assert least == pytest.approx(0.06452, rel=1e-3)
+    # against the dense pricing of the same launch: 66 ops a pair, not 6
+    assert roofline.kernel_ops(n, k, w, c) / ops == pytest.approx(11.0)
+
+
+def test_touched_words_set_the_bytes_term():
+    """64 targets of one word each over 4M rows: 256 ops a row.  Touching
+    all 32 words, a row's 34 words of bytes set the bound; touching 2, its
+    4 words take less time than the ops, which then set it."""
+    p = PEAKS[V5E]
+    n, k, w, c = 4_000_000, 64, 32, 2
+    ops = 4_000_000 * (2 * 64 + 64 * 2)
+    assert roofline.kernel_ops(n, k, w, c, 64) == ops
+    wide = 4 * (4_000_000 * 32 + 4_000_000 * 2 + 64 + 128)
+    narrow = 4 * (4_000_000 * 2 + 4_000_000 * 2 + 64 + 128)
+    assert roofline.kernel_bytes(n, k, w, c, 64, 32) == wide
+    assert roofline.kernel_bytes(n, k, w, c, 64, 2) == narrow
+    assert roofline.least_seconds(n, k, w, c, p, 64, 32) == \
+        (wide / 819e9, "bytes")
+    least, bound = roofline.least_seconds(n, k, w, c, p, 64, 2)
+    assert bound == "ops" and least == pytest.approx(ops / p.int32_ops)
+    assert narrow / 819e9 < least < wide / 819e9
 
 
 def test_share_is_ops_bound_for_a_wide_target_block():

@@ -105,3 +105,90 @@ def test_a_program_without_the_new_spans_reads_none():
     for metric in ("count_host_max_ms.serve", "job_host_p50_ms.bulk",
                    "submit_wait_p95_ms.serve"):
         assert read(metric, [call, query, submit]) is None
+
+
+def _flush(t0, n_masks, words=None, program_words=None, base=4_000_000,
+           delta=40_000):
+    """A ``serve.count`` span with its targets' words (recorded by the
+    benchmark's instant beneath it, or by the span itself) and a base and
+    a delta launch of 256 targets beneath it."""
+    from bench.target_words import INSTANT
+
+    count = span("serve.count", t0, t0 + 0.03, n_masks=n_masks,
+                 **(program_words or {}))
+    out = [count]
+    if words is not None:
+        out.append(span(INSTANT, t0 + 0.001, t0 + 0.001, count, **words))
+    store = span("store.counts", t0 + 0.002, t0 + 0.03, count)
+    out.append(store)
+    for i, n in enumerate((base, delta)):
+        out.append(span("kernel.count", t0 + 0.003 + 0.01 * i,
+                        t0 + 0.012 + 0.01 * i, store, n=n, k=256, w=32,
+                        c=2))
+    return out
+
+
+def _least(n, k, w, c, held, touched):
+    from bench import roofline
+    from bench.peaks import PEAKS
+
+    return roofline.least_seconds(n, k, w, c, PEAKS["TPU v5 lite"], held,
+                                  touched)[0]
+
+
+def _roofline_ctx(spans, kernel_s):
+    return {"spans": spans, "trace": {"kernel_s": kernel_s},
+            "device_kind": "TPU v5 lite"}
+
+
+@pytest.mark.parametrize("where", ["benchmark instant", "program span"])
+def test_roofline_prices_each_launch_by_its_targets_words(where, capsys):
+    from bench.readings import launches, priced_launches, roofline
+
+    words = {"mask_words": 141, "union_words": 31}
+    spans = _flush(1.0, 72, **({"words": words}
+                               if where == "benchmark instant"
+                               else {"program_words": words}))
+    # base and delta launches share the flush's 72 targets and words
+    assert priced_launches({"spans": spans}) == [
+        (4_000_000, 72, 32, 2, 141, 31), (40_000, 72, 32, 2, 141, 31)]
+    assert [r[4] for r in launches({"spans": spans})] == [72, 72]
+    least = _least(4_000_000, 72, 32, 2, 141, 31) + \
+        _least(40_000, 72, 32, 2, 141, 31)
+    assert roofline(_roofline_ctx(spans, 0.02)) == \
+        pytest.approx(100 * least / 0.02)
+    assert "2 launches, 0 of them priced as dense" in capsys.readouterr().err
+
+
+def test_the_programs_own_record_comes_before_the_benchmarks():
+    from bench.readings import priced_launches
+
+    spans = _flush(1.0, 72, words={"mask_words": 999, "union_words": 32},
+                   program_words={"mask_words": 141, "union_words": 31})
+    assert {p[4:] for p in priced_launches({"spans": spans})} == {(141, 31)}
+
+
+def test_words_are_held_to_the_launch_width():
+    from bench.readings import priced_launches
+
+    spans = _flush(1.0, 2, words={"mask_words": 90, "union_words": 40})
+    assert {p[4:] for p in priced_launches({"spans": spans})} == \
+        {(64, 32)}
+
+
+def test_a_launch_without_a_record_of_words_is_priced_dense(capsys):
+    from bench.readings import priced_launches, roofline
+
+    recorded = _flush(1.0, 72, words={"mask_words": 141, "union_words": 31})
+    bare = _flush(2.0, 100)
+    orphan = span("kernel.count", 3.0, 3.01, n=4_000_000, k=256, w=32, c=2)
+    spans = recorded + bare + [orphan]
+    priced = priced_launches({"spans": spans})
+    assert [p[4:] for p in priced] == [(141, 31)] * 2 + [(None, None)] * 3
+    assert [p[1] for p in priced] == [72, 72, 100, 100, 256]
+    least = sum(_least(*p) for p in priced)
+    dense = _least(4_000_000, 100, 32, 2, 100 * 32, 32)
+    assert dense == _least(4_000_000, 100, 32, 2, None, None)
+    assert roofline(_roofline_ctx(spans, 1.0)) == pytest.approx(100 * least)
+    assert "5 launches, 3 of them priced as dense" in \
+        capsys.readouterr().err
